@@ -9,13 +9,12 @@ sweep (from the PA split) + measured wall-clock on 8 fake host devices
 
 from __future__ import annotations
 
-import os
 import subprocess
 import sys
 
 from repro.graphs import partition_1d, pa_split
 
-from .common import emit, graph
+from .common import ROOT, emit, fake_device_env, graph
 
 _SUB = r"""
 import os
@@ -24,7 +23,8 @@ import time, numpy as np
 import jax, jax.numpy as jnp
 from repro.graphs import standin, partition_1d, pa_split
 from repro.dist.collectives import push_exchange, pull_exchange
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+from repro.launch.mesh import make_local_mesh
+mesh = make_local_mesh()
 g = standin("orc", scale=1.0/256)
 part = partition_1d(g.n, 8)
 local, remote, stats = pa_split(g, part)
@@ -52,11 +52,9 @@ def run():
         emit(f"dm_bytes_P{P}", 0.0,
              f"cut={stats['cut_edges']};mp_push={mp_bytes};"
              f"rma_pull={pull_bytes};rma_push={rma_push_bytes}")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", _SUB], capture_output=True,
-                       text=True, timeout=600, env=env, cwd="/root/repo")
+                       text=True, timeout=600, env=fake_device_env(),
+                       cwd=ROOT)
     for line in r.stdout.splitlines():
         if "," in line:
             name, dt, nbytes = line.split(",")

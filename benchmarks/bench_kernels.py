@@ -10,7 +10,9 @@ check they agree, and emit one schema-validated ``kernel_cell`` row
 (``benchmarks/schema.json``). Every row also reports its analytic
 roofline anchors — ``bytes_moved``, ``flops``, ``pct_roofline`` (via
 ``repro.roofline.analysis.kernel_roofline``) — so the trajectory tracks
-distance-to-hardware, not just distance-to-jnp.
+distance-to-hardware, not just distance-to-jnp. The bound is always the
+v5e target's: on a CPU these rows time the Pallas interpreter, and
+their ``pct_roofline`` says nothing about a chip.
 
     PYTHONPATH=src python -m benchmarks.run --only kernels \
         --json BENCH_kernels.json
@@ -139,7 +141,7 @@ def run():
     from repro.kernels.coo_push import build_push_plan, coo_push_pallas
     from repro.kernels.ell_spmv import ell_spmv_pallas
     from repro.kernels.tune import tune_pull, tune_push
-    from repro.roofline.analysis import kernel_roofline
+    from repro.roofline.analysis import V5E, kernel_roofline
 
     combines = ("sum",) if common.SMOKE else ("sum", "min")
     batches = (1, 8)
@@ -162,7 +164,8 @@ def run():
                     block_n=block_n)
                 us_pal = timeit(pallas_pull, iters=iters)
                 roof = kernel_roofline(
-                    "pull", n=g.n, d_ell=g.d_ell, batch=batch,
+                    "pull", device_kind=V5E, n=g.n, d_ell=g.d_ell,
+                    batch=batch,
                     itemsize=x.dtype.itemsize, measured_us=us_pal)
                 cell = _cell("pull", combine, gname, g, batch, {
                     "block_n": int(block_n),
@@ -194,7 +197,7 @@ def run():
                     block_n=pbn, plan=plan, strategy=strategy)
                 us_pal = timeit(pallas_push, iters=iters)
                 roof = kernel_roofline(
-                    "push", n=g.n, batch=batch,
+                    "push", device_kind=V5E, n=g.n, batch=batch,
                     itemsize=x.dtype.itemsize, nb=plan.nb, cap=plan.cap,
                     bin_n=plan.bin_n, measured_us=us_pal)
                 cell = _cell("push", combine, gname, g, batch, {
@@ -254,7 +257,8 @@ def run():
                         combine, rows_n, block_r)
                     us_pal = timeit(pallas_f, iters=iters)
                     roof = kernel_roofline(
-                        "pullf", n=rows_n, d_ell=g.d_ell, batch=batch,
+                        "pullf", device_kind=V5E, n=rows_n,
+                        d_ell=g.d_ell, batch=batch,
                         itemsize=x.dtype.itemsize, measured_us=us_pal)
                     cell = _cell("pullf", combine, gname, g, batch, {
                         "block_n": int(block_r),

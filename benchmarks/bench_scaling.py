@@ -20,7 +20,6 @@ payload (benchmarks/schema.json); ``benchmarks.validate`` enforces it.
 
 from __future__ import annotations
 
-import os
 import subprocess
 import sys
 
@@ -103,13 +102,10 @@ for P in (1, 2, 4, 8):
 def run():
     scale = 1.0 / 1024 if common.SMOKE else 1.0 / 256
     iters = 1 if common.SMOKE else 3
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    env.pop("XLA_FLAGS", None)
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(
         [sys.executable, "-c", _SUB % {"scale": scale, "iters": iters}],
-        capture_output=True, text=True, timeout=1200, env=env, cwd=root)
+        capture_output=True, text=True, timeout=1200,
+        env=common.fake_device_env(), cwd=common.ROOT)
     for line in r.stdout.splitlines():
         if line.startswith("ROW\t"):
             _, name, us, derived = line.split("\t", 3)

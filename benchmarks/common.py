@@ -9,6 +9,7 @@ paper's graphs are structurally matched synthetic stand-ins
 
 from __future__ import annotations
 
+import os
 import time
 from functools import lru_cache
 
@@ -30,6 +31,26 @@ TELEMETRY = None
 ROWS: list[str] = []
 # structured mirror of ROWS, consumed by `benchmarks.run --json PATH`
 RESULTS: list[dict] = []
+
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def fake_device_env() -> dict:
+    """Environment for a child process that fakes host CPU devices
+    (its script sets ``XLA_FLAGS`` itself), pinned to the CPU. Refuses
+    on a TPU host: this process already holds the chip, and a child
+    that reaches for it would fail or hang."""
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "this suite fakes devices in a child process and cannot run "
+            "on a TPU host; run the sharded path in-process there "
+            "(python chip_smoke.py --chips 4)")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
+    env.pop("XLA_FLAGS", None)
+    return env
 
 
 def emit(name: str, us_per_call: float, derived: str = ""):

@@ -10,7 +10,7 @@ import json
 import os
 
 from repro.configs import ARCH_FAMILY, full_config, shape_table
-from repro.roofline.analysis import HW, model_flops
+from repro.roofline.analysis import model_flops
 
 from .common import emit
 

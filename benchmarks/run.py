@@ -31,6 +31,8 @@ import json
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 SUITES = [
     ("api_solve", "bench_api"),
     ("pushpull_matrix", "bench_auto"),
@@ -247,6 +249,7 @@ def main() -> None:
                          "Perfetto-loadable .chrome.json next to it); "
                          "auto-policy matrix rows gain mispredict%%")
     args = ap.parse_args()
+    enable_compile_cache()
     from . import common
     common.SMOKE = args.smoke
     if args.trace_out:

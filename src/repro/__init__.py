@@ -7,34 +7,6 @@ adapted to TPU/XLA semantics, plus the assigned architecture zoo.
 
 __version__ = "1.0.0"
 
-# --- jax.shard_map compatibility -------------------------------------
-# The framework (models, dist collectives, tests) targets the modern
-# `jax.shard_map(..., check_vma=...)` spelling. On older jax releases the
-# function lives in jax.experimental.shard_map and the kwarg is named
-# `check_rep`; alias it once here so every repro import sees one API.
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _compat_shard_map(f=None, mesh=None, in_specs=None, out_specs=None,
-                          check_vma=None, **kw):
-        if check_vma is not None:
-            kw["check_rep"] = bool(check_vma)
-        else:
-            # legacy check_rep rejects valid ppermute/axis_index patterns
-            # our exchanges use; modern check_vma handles them.
-            kw.setdefault("check_rep", False)
-        if f is None:          # decorator-factory style
-            return lambda fn: _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                         out_specs=out_specs, **kw)
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-
-    _jax.shard_map = _compat_shard_map
-
-del _jax
-
 
 def __getattr__(name):
     # `repro.api` without forcing the full algorithm import at package

@@ -35,9 +35,11 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh
 
 from ..graphs.structure import Graph
-from ..resilience import CircuitBreaker, fault_point, note
+from ..resilience import CircuitBreaker, FaultInjected, fault_point, note
 from .cost_model import Cost, counter, counter_dtype
 from .direction import Direction
 from .primitives import (COMBINE_FNS, combine_identity, frontier_in_edges,
@@ -117,6 +119,20 @@ class ExchangeBackend:
             lambda v, f, c: self.push(g, v, f, combine, msg_fn, c),
             lambda v, f, c: self.pull(g, v, touched, combine, msg_fn, c),
             values, frontier, cost)
+
+    # -- graph-sized views the engine passes in ----------------------------
+    def operands(self):
+        """Arrays this backend reads inside the engine's compiled loop
+        (views built by ``prepare``). The engine passes them as
+        arguments of its program: closed over, they would be baked into
+        it as constants, which no compiler accepts at real graph sizes.
+        The default holds none."""
+        return ()
+
+    def bind(self, operands) -> "ExchangeBackend":
+        """This backend reading ``operands`` (the engine's traced
+        arguments) in place of its own arrays."""
+        return self
 
     # -- cross-step exchange state (sharded/compressed backends) ----------
     def init_exchange_state(self, g: Graph):
@@ -251,7 +267,6 @@ def classify_msg_fn(msg_fn: Optional[Callable]) -> Optional[str]:
         return _MSG_CACHE[msg_fn]
     except (KeyError, TypeError):
         pass
-    import numpy as np
     mode = None
     try:
         # escape any ambient jit trace: the probe must execute eagerly
@@ -309,14 +324,20 @@ class PallasBackend(EllBackend):
 
     Cells outside the kernels' coverage — a ``msg_fn`` that is not one
     of the three wire-message shapes, a combine outside {sum, max, min},
-    payload rank > 2, or a dtype outside float32/float64/int32/int64 —
-    fall back to the jnp primitives (``EllBackend``'s paths), charging
-    identical costs, so every (algorithm × policy) cell keeps running.
+    payload rank > 2, a dtype outside float32/float64/int32/int64, or a
+    64-bit payload on a compiled (TPU) kernel — fall back to the jnp
+    primitives (``EllBackend``'s paths), charging identical costs and
+    counting ``fallback_*``, so every (algorithm × policy) cell keeps
+    running. A kernel that *fails* is not served from jnp: the error
+    propagates, naming the kernel. Only an injected fault
+    (``repro.resilience``) takes the degradation ladder.
 
         >>> r = api.solve(g, "bfs", root=0, backend="pallas")  # doctest: +SKIP
 
     ``stats`` counts trace-time dispatch decisions (kernel vs fallback,
-    per direction) — observability for tests and benchmarks.
+    per direction) — observability for tests and benchmarks — plus
+    ``fallback_push_overflow``, counted at run time each time a traced
+    push's bin capacity overflows and the jnp branch serves the step.
     """
     # the ELL-in gather no longer scans all edges when a touched set is
     # given: the frontier kernel restricts it, and predict_pull_scan
@@ -336,6 +357,7 @@ class PallasBackend(EllBackend):
                                  "kernel_pull_frontier": 0,
                                  "skip_empty_pull": 0,
                                  "fallback_pull": 0, "fallback_push": 0,
+                                 "fallback_push_overflow": 0,
                                  "fault_fallback_pull": 0,
                                  "fault_fallback_push": 0,
                                  "breaker_skip_pull": 0,
@@ -362,12 +384,20 @@ class PallasBackend(EllBackend):
         return self is other
 
     # -- dispatch help -----------------------------------------------------
+    def _compiled(self) -> bool:
+        from ..kernels.ell_spmv import default_interpret
+        return not (default_interpret() if self.interpret is None
+                    else self.interpret)
+
     def _mode(self, values, combine, msg_fn) -> Optional[str]:
+        from ..kernels.ell_spmv import compiled_dtype_ok
         if combine not in ("sum", "max", "min"):
             return None
         if values.ndim not in (1, 2):
             return None
         if str(values.dtype) not in _PALLAS_DTYPES:
+            return None
+        if self._compiled() and not compiled_dtype_ok(values.dtype):
             return None
         return classify_msg_fn(msg_fn)
 
@@ -382,7 +412,9 @@ class PallasBackend(EllBackend):
             self._tuned[key] = (
                 tune_pull(g.n, g.d_ell, width, values.dtype, combine,
                           mode, self.interpret)
-                if self.autotune else pull_candidates(g.n)[0])
+                if self.autotune else pull_candidates(
+                    g.n, width, d_ell=g.d_ell,
+                    compiled=self._compiled())[0])
         return self._tuned[key]
 
     def _push_blocks(self, g: Graph, values, combine,
@@ -397,7 +429,8 @@ class PallasBackend(EllBackend):
             self._tuned[key] = (
                 tune_push(g.n, g.m, width, values.dtype, combine, mode,
                           self.interpret)
-                if self.autotune else push_candidates(g.n, g.m)[0])
+                if self.autotune else push_candidates(
+                    g.n, g.m, width=width, compiled=self._compiled())[0])
         be, bn, strat = self._tuned[key]
         # partial pins override only their own component
         if self.block_e is not None:
@@ -458,7 +491,9 @@ class PallasBackend(EllBackend):
                                    values.dtype, combine, mode,
                                    self.interpret)
                 if self.autotune
-                else pull_frontier_candidates(g.n, rows)[0])
+                else pull_frontier_candidates(
+                    g.n, rows, width=width, d_ell=g.d_ell,
+                    compiled=self._compiled())[0])
         return self._tuned[key]
 
     def _pull_scan_stats(self, g: Graph, touched):
@@ -497,9 +532,9 @@ class PallasBackend(EllBackend):
         return out
 
     def _kernel_failed(self, cell, direction: str, exc) -> None:
-        """One rung down the ladder: count the failure, inform the
-        breaker, surface a resilience event. The caller then serves
-        this call from the jnp fallback."""
+        """One rung down the ladder after an injected fault: count it,
+        inform the breaker, surface a resilience event. The caller then
+        serves this call from the jnp fallback."""
         self.stats[f"fault_fallback_{direction}"] += 1
         opened = self.breaker.record_failure(cell)
         note(f"fallback.pallas.{direction}",
@@ -525,9 +560,9 @@ class PallasBackend(EllBackend):
             fault_point("pallas.pull")
             out = self._pull_kernel(g, values, touched, combine, mode,
                                     cost)
-        except Exception as exc:   # noqa: BLE001 — the ladder catches
-            # dispatch/trace-time kernel failure: degrade to the jnp
-            # path (identical semantics, full-scan pricing)
+        except FaultInjected as exc:
+            # injected dispatch fault: degrade to the jnp path
+            # (identical semantics, full-scan pricing)
             self._kernel_failed(cell, "pull", exc)
             return super().pull(g, values, touched, combine, msg_fn, cost)
         self.breaker.record_success(cell)
@@ -628,12 +663,15 @@ class PallasBackend(EllBackend):
             fault_point("pallas.push")
             out = self._push_kernel(g, values, frontier, combine, mode,
                                     cost)
-        except Exception as exc:   # noqa: BLE001 — the ladder catches
+        except FaultInjected as exc:
             self._kernel_failed(cell, "push", exc)
             return super().push(g, values, frontier, combine, msg_fn,
                                 cost)
         self.breaker.record_success(cell)
         return out
+
+    def _count_overflow(self) -> None:
+        self.stats["fallback_push_overflow"] += 1
 
     def _push_kernel(self, g, values, frontier, combine, mode, cost):
         from ..kernels.coo_push import (bin_plan_traced, coo_push_pallas,
@@ -666,12 +704,14 @@ class PallasBackend(EllBackend):
                                       block_e))
             plan, fits = bin_plan_traced(
                 g.coo_src, g.coo_dst, g.coo_w, g.in_ptr, g.n, block_n,
-                cap=cap, align=block_e, max_run=g.d_ell)
-            out = jax.lax.cond(
-                fits,
-                lambda v, f: kernel(v, f, plan),
-                lambda v, f: _coo_push_jnp(g, v, f, combine, mode),
-                values, frontier)
+                cap=cap, align=block_e)
+
+            def overflow(v, f):
+                jax.debug.callback(self._count_overflow)
+                return _coo_push_jnp(g, v, f, combine, mode)
+
+            out = jax.lax.cond(fits, lambda v, f: kernel(v, f, plan),
+                               overflow, values, frontier)
         k = frontier_out_edges(g, frontier)
         width = 1 if values.ndim == 1 else values.shape[-1]
         # the phase-1 binning pass reads and rewrites every edge once
@@ -742,7 +782,13 @@ class DistributedBackend(ExchangeBackend):
         from ..graphs.partition import (pa_regroup_by_dst, pa_split,
                                         partition_1d)
         if mesh is None:
-            mesh = jax.make_mesh((jax.device_count(), 1), (axis, "model"))
+            mesh = Mesh(np.array(jax.devices()).reshape(-1, 1),
+                        (axis, "model"))
+        else:
+            # Auto axes: the exchanges slice the sharded [n_padded]
+            # result down to [n], which Explicit axes reject whenever n
+            # is not a multiple of the shard count
+            mesh = Mesh(mesh.devices, mesh.axis_names)
         if num_parts is None:
             num_parts = mesh.shape[axis]
         if num_parts != mesh.shape[axis]:

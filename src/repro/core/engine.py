@@ -378,9 +378,22 @@ class PushPullEngine:
         return self._finish_phase(g, phase, fin, steps0, pushes0)
 
     # -- the full program: phases under an epoch loop ---------------------
-    @partial(jax.jit, static_argnames=("self",))
     def run(self, g: Graph, init_state: Any,
             init_frontier: jax.Array) -> EngineResult:
+        return self._run(g, init_state, init_frontier,
+                         self.backend.operands())
+
+    @partial(jax.jit, static_argnames=("self",))
+    def _run(self, g: Graph, init_state: Any, init_frontier: jax.Array,
+             operands: Any) -> EngineResult:
+        # the backend's graph-sized views enter as arguments (closed
+        # over, they would be baked into the program as constants)
+        bound = dataclasses.replace(self,
+                                    backend=self.backend.bind(operands))
+        return bound._run_body(g, init_state, init_frontier)
+
+    def _run_body(self, g: Graph, init_state: Any,
+                  init_frontier: jax.Array) -> EngineResult:
         if isinstance(self.program, PhaseProgram):
             pp = self.program
             phases = tuple(pp.phases)

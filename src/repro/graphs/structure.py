@@ -161,15 +161,13 @@ def build_graph(src, dst, n: int, weights=None, d_ell: Optional[int] = None,
     order = np.argsort(dst, kind="stable")
     p_src, p_dst, p_w = src[order], dst[order], w[order]
     in_ptr = np.zeros(n + 1, dtype=np.int32)
-    np.add.at(in_ptr, p_dst + 1, 1)
-    in_ptr = np.cumsum(in_ptr, dtype=np.int64).astype(np.int32)
+    in_ptr[1:] = np.cumsum(np.bincount(p_dst, minlength=n))
 
     # push-major: sort by src
     order2 = np.argsort(src, kind="stable")
     q_src, q_dst, q_w = src[order2], dst[order2], w[order2]
     out_ptr = np.zeros(n + 1, dtype=np.int32)
-    np.add.at(out_ptr, q_src + 1, 1)
-    out_ptr = np.cumsum(out_ptr, dtype=np.int64).astype(np.int32)
+    out_ptr[1:] = np.cumsum(np.bincount(q_src, minlength=n))
 
     in_deg = np.diff(in_ptr).astype(np.int32)
     out_deg = np.diff(out_ptr).astype(np.int32)

@@ -9,60 +9,64 @@ version removes the contention instead of serializing around it:
 **Phase 1 — binning.** Edges are regrouped by destination *bin*: bin
 ``b`` owns destinations ``[b·bin_n, (b+1)·bin_n)``. Because the COO
 edges are already dst-sorted, each bin is a *contiguous slice* of the
-edge list; the layout is a padded ``[nb, cap]`` matrix plus a per-bin
-CSR row pointer ``ptr[nb, bin_n+1]`` locating every destination's run
-of edges inside its bin. Host-side the regroup goes through the
-existing :func:`~repro.graphs.partition.pa_regroup_by_dst` primitive
-(:func:`build_push_plan`); under a trace (the engine jits the graph)
-the same layout is gathered from ``in_ptr`` (:func:`bin_plan_traced`)
-with a static capacity and a runtime fits guard.
+edge list, so the layout — a padded ``[nb, cap]`` matrix — is one
+windowed slice per bin at ``in_ptr``-derived offsets
+(:func:`bin_plan_traced`); :func:`build_push_plan` does the same for a
+concrete graph with the exact capacity, and under a trace (the engine
+jits the graph) the capacity is static with a runtime fits guard.
 
-**Phase 2 — per-bin reduce.** The grid runs *in parallel over
-destination bins* (axis 0) while streaming edge blocks (axis 1, which
-Pallas double-buffers); each bin owns a private ``[bin_n(, B)]``
-accumulator block, so no two grid cells ever write the same
-destination — contention-free by construction, no atomics, no
-sequential grid. Two reduce strategies, selected by the autotuner:
+**Phase 2 — per-bin reduce.** XLA gathers each edge's source payload
+and frontier bit and forms the messages in the plan layout, transposed
+to ``[nb, B, cap]`` (edges on the 128-wide lane axis) — Mosaic cannot
+gather with a vector of indices inside a tile. The kernel's grid runs
+*in parallel over destination bins* (axis 0) while streaming edge
+blocks (axis 1, which Pallas double-buffers); each bin owns a private
+``[B, bin_n]`` accumulator block, so no two grid cells ever write the
+same destination — contention-free by construction, no atomics, no
+sequential grid. Each edge block is matched against the bin's rows as
+a ``[bin_n, block_e]`` one-hot, reduced by one of two strategies,
+selected by the autotuner:
 
-  * ``"scan"`` — bandwidth-bound: float/int sums gather two prefix
-    sums at each destination's run boundaries (``cumsum`` + ``ptr``
-    difference; floats accumulate the prefix in float64 so the
-    difference never cancels below the parity tolerance), min/max use
-    a segmented log-step (Hillis–Steele) scan over the dst runs. Work
-    is O(cap + bin_n) per bin — what the roofline says this memory-
-    bound kernel should cost.
-  * ``"mxu"`` — compute-bound: the one-hot-matmul sum (float sums hit
-    the MXU) and the masked window reduce (min/max, integer sums).
-    O(bin_n × cap) multiply-accumulates per bin, which the MXU does
-    essentially for free on real TPUs but the interpreter does not.
+  * ``"scan"`` — the VPU: every row scans the edge block through the
+    one-hot mask and reduces over the lanes (a masked window reduce);
+    the ``[bin_n, 1]`` column is turned into a lane-major row by one
+    aligned tile transpose. Covers every combine and dtype.
+  * ``"mxu"`` — float sums as a one-hot matmul on the MXU (messages
+    ``[B, block_e]`` against the ``[bin_n, block_e]`` one-hot,
+    f32-exact precision); other cells reduce as ``"scan"`` does.
+
+Both do ``O(bin_n × cap)`` work per bin, which bounds the bin width.
 
 Sentinel discipline: padded slots carry ``n`` on both endpoints and
-weight 0; they are masked to the combine identity and, in the scan
-strategy, live beyond ``ptr[bin_n]`` so the boundary gathers never
-read them. Destinations with no (active) in-edge hold the combine
-identity — including whole edgeless bins (all-padding blocks).
+weight 0; their messages are the combine identity, and their
+destination matches no row. Destinations with no (active) in-edge hold
+the combine identity — including whole edgeless bins (all-padding
+blocks).
 
 Production surface matches ``ell_spmv_pallas``: combine ∈
-{sum, max, min}, payloads [n] or [n, B], float32/float64/int32/int64,
-msg ∈ {"mul", "copy", "add"}, ``interpret=None`` auto-detect.
+{sum, max, min}, payloads [n] or [n, B], 32-bit payloads compiled (any
+width interpreted), msg ∈ {"mul", "copy", "add"}, ``interpret=None``
+auto-detect.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 
 from ..core.primitives import combine_identity
-from .ell_spmv import default_interpret
+from .ell_spmv import (Z, check_compiled, compiler_params,
+                       default_interpret, msg_dtype, reduce_keep)
 
 __all__ = ["PushBinPlan", "build_push_plan", "bin_plan_traced",
-           "default_bin_cap", "coo_push_pallas", "PUSH_STRATEGIES"]
+           "default_bin_cap", "coo_push_pallas", "push_vmem_bytes",
+           "PUSH_STRATEGIES"]
 
 PUSH_STRATEGIES = ("scan", "mxu")
 
@@ -78,67 +82,37 @@ class PushBinPlan:
 
     ``src/dst/w`` are ``[nb, cap]`` — row ``b`` holds the (dst-sorted)
     edges whose destination falls in bin ``b``, padded with the
-    sentinel ``n`` / weight 0. ``ptr`` is ``int32[nb, bin_n+1]``: the
-    within-bin CSR row pointer (destination ``b·bin_n + j`` owns slots
-    ``ptr[b, j]:ptr[b, j+1]`` of row ``b``; ``ptr[b, bin_n]`` is the
-    bin's true edge count, so everything at or beyond it is padding).
-    ``max_run`` bounds the longest single-destination run — it sizes
-    the segmented scan's static pass count.
+    sentinel ``n`` / weight 0.
     """
     src: jax.Array   # int32[nb, cap]
     dst: jax.Array   # int32[nb, cap]
     w: jax.Array     # [nb, cap]
-    ptr: jax.Array   # int32[nb, bin_n+1]
     bin_n: int = dataclasses.field(metadata=dict(static=True))
     cap: int = dataclasses.field(metadata=dict(static=True))
     nb: int = dataclasses.field(metadata=dict(static=True))
-    max_run: int = dataclasses.field(metadata=dict(static=True))
+
+
+def _bin_offsets(in_ptr, n: int, bin_n: int, nb: int):
+    """First edge slot of every bin, plus the end of the last one."""
+    starts = np.minimum(np.arange(nb + 1) * bin_n, n)
+    return in_ptr[starts]
 
 
 def build_push_plan(src, dst, w, n: int, bin_n: int,
                     align: int = 128) -> PushBinPlan:
-    """Host-side (concrete-graph) binning pass.
-
-    Promotes :func:`~repro.graphs.partition.pa_regroup_by_dst` into the
-    kernel path: the destination-owner regroup that packs the
-    distributed pull exchange is exactly the phase-1 bin layout, with
-    ``shard_size = bin_n`` and the row capacity aligned to the edge
-    block so the reduce grid divides evenly. The within-bin order is
-    the dst-sorted input order (the regroup is stable), which the scan
-    strategy's run pointers require.
-    """
-    from ..graphs.partition import (Partition, PartitionedEdges,
-                                    pa_regroup_by_dst)
-    nb = max(1, _round_up(n, bin_n) // bin_n)
-    part = Partition(n=n, num_parts=nb, shard_size=bin_n,
-                     n_padded=nb * bin_n)
-    src = np.asarray(src)
+    """Host-side (concrete-graph) binning pass: the same windowed layout
+    as :func:`bin_plan_traced`, with the capacity set to the fullest
+    bin (aligned to the edge block, so the reduce grid divides evenly).
+    Requires dst-sorted edges, which ``Graph.coo_*`` are."""
     dst = np.asarray(dst)
-    w = np.asarray(w)
-    m = int(src.shape[0])
-    flat = PartitionedEdges(
-        src=jnp.asarray(src.reshape(1, -1), jnp.int32)
-        if m else jnp.full((1, 1), n, jnp.int32),
-        dst=jnp.asarray(dst.reshape(1, -1), jnp.int32)
-        if m else jnp.full((1, 1), n, jnp.int32),
-        w=jnp.asarray(w.reshape(1, -1), jnp.float32)
-        if m else jnp.zeros((1, 1), jnp.float32),
-        valid=jnp.asarray(np.ones((1, max(m, 1)), bool)
-                          if m else np.zeros((1, 1), bool)),
-        count=jnp.asarray([m], jnp.int32), cap=max(m, 1), num_parts=1)
-    binned = pa_regroup_by_dst(part, flat, n, align=align)
-    bd = np.asarray(binned.dst)
-    # per-bin CSR over the bin-relative destinations (rows are sorted:
-    # the regroup preserves the dst-sorted input order)
-    rel = np.where(bd < n, bd - np.arange(nb)[:, None] * bin_n, bin_n)
-    ptr = np.stack([np.searchsorted(rel[b], np.arange(bin_n + 1))
-                    for b in range(nb)]).astype(np.int32)
-    runs = np.diff(ptr, axis=1)
-    max_run = int(runs.max()) if runs.size else 1
-    return PushBinPlan(src=binned.src, dst=binned.dst, w=binned.w,
-                       ptr=jnp.asarray(ptr), bin_n=int(bin_n),
-                       cap=int(binned.cap), nb=int(nb),
-                       max_run=max(max_run, 1))
+    nb = max(1, _round_up(n, bin_n) // bin_n)
+    in_ptr = np.searchsorted(dst, np.arange(n + 1)).astype(np.int32)
+    cap = int(np.diff(_bin_offsets(in_ptr, n, bin_n, nb)).max())
+    plan, _ = bin_plan_traced(
+        jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32),
+        jnp.asarray(w, jnp.float32), jnp.asarray(in_ptr), n, bin_n,
+        cap=max(cap, 1), align=align)
+    return plan
 
 
 def default_bin_cap(n: int, m: int, d_ell: int, bin_n: int,
@@ -155,17 +129,14 @@ def default_bin_cap(n: int, m: int, d_ell: int, bin_n: int,
 
 
 def bin_plan_traced(src, dst, w, in_ptr, n: int, bin_n: int, cap: int,
-                    align: int = 128, max_run: int | None = None
-                    ) -> tuple[PushBinPlan, jax.Array]:
+                    align: int = 128) -> tuple[PushBinPlan, jax.Array]:
     """In-trace binning pass (the engine jits the graph, so the host
     regroup is unavailable). dst-sorted edges make every bin a
-    contiguous slice of the edge list: the layout is one gather at
-    ``in_ptr``-derived offsets — O(nb·cap) reads, no scatter. Returns
-    ``(plan, fits)`` where ``fits`` is the runtime guard (true iff no
-    bin overflows the static ``cap``); callers branch to the jnp
-    segment fallback when it fails. ``max_run`` must be a static upper
-    bound on any destination's in-degree (the graph's ``d_ell``
-    qualifies); it defaults to ``cap``."""
+    contiguous slice of the edge list: the layout is one ``cap``-wide
+    window per bin at ``in_ptr``-derived offsets — O(nb·cap) contiguous
+    reads, no scatter. Returns ``(plan, fits)`` where ``fits`` is the
+    runtime guard (true iff no bin overflows the static ``cap``);
+    callers branch to the jnp segment fallback when it fails."""
     m = src.shape[0]
     nb = max(1, _round_up(n, bin_n) // bin_n)
     cap = _round_up(max(cap, 1), max(align, 1))
@@ -174,136 +145,76 @@ def bin_plan_traced(src, dst, w, in_ptr, n: int, bin_n: int, cap: int,
             src=jnp.full((nb, cap), n, jnp.int32),
             dst=jnp.full((nb, cap), n, jnp.int32),
             w=jnp.zeros((nb, cap), w.dtype),
-            ptr=jnp.zeros((nb, bin_n + 1), jnp.int32),
-            bin_n=int(bin_n), cap=int(cap), nb=int(nb),
-            max_run=1), jnp.bool_(True)
-    starts = jnp.minimum(jnp.arange(nb + 1, dtype=jnp.int32) * bin_n, n)
-    off = in_ptr[starts]                             # [nb+1]
+            bin_n=int(bin_n), cap=int(cap), nb=int(nb)), jnp.bool_(True)
+    off = _bin_offsets(in_ptr, n, bin_n, nb)         # [nb+1]
     counts = off[1:] - off[:-1]
     fits = jnp.max(counts) <= cap
-    pos = off[:-1, None] + jnp.arange(cap, dtype=jnp.int32)[None, :]
     in_bin = jnp.arange(cap, dtype=jnp.int32)[None, :] < counts[:, None]
-    pos = jnp.where(in_bin, pos, m)                  # padding -> fill
-    bsrc = jnp.take(src, pos, mode="fill", fill_value=n)
-    bdst = jnp.take(dst, pos, mode="fill", fill_value=n)
-    bw = jnp.take(w, pos, mode="fill", fill_value=0)
-    ridx = jnp.minimum(
-        starts[:-1, None] + jnp.arange(bin_n + 1, dtype=jnp.int32)[None],
-        n)
-    ptr = jnp.minimum(in_ptr[ridx] - off[:-1, None], cap).astype(
-        jnp.int32)
-    return PushBinPlan(src=bsrc, dst=bdst, w=bw, ptr=ptr,
-                       bin_n=int(bin_n), cap=int(cap), nb=int(nb),
-                       max_run=int(cap if max_run is None
-                                   else min(max_run, cap))), fits
+
+    def windows(a, fill):
+        # padding the tail by cap keeps every window in bounds, so no
+        # start index is clamped (which would shift the window)
+        a = jnp.concatenate([a, jnp.full((cap,), fill, a.dtype)])
+        rows = jax.vmap(lambda o: lax.dynamic_slice(a, (o,), (cap,)))(
+            off[:-1])
+        return jnp.where(in_bin, rows, jnp.asarray(fill, a.dtype))
+
+    return PushBinPlan(src=windows(src, n), dst=windows(dst, n),
+                       w=windows(w, 0), bin_n=int(bin_n), cap=int(cap),
+                       nb=int(nb)), fits
 
 
-def _acc_combine(acc, local, combine: str):
-    if combine == "sum":
-        return acc + local
-    if combine == "max":
-        return jnp.maximum(acc, local)
-    return jnp.minimum(acc, local)
+def push_vmem_bytes(block_e: int, bin_n: int, width: int = 1) -> int:
+    """VMEM working set of one grid step: double-buffered message and
+    destination blocks, the resident accumulator, and the
+    ``[bin_n, block_e]`` one-hot and masked window."""
+    return (2 * (width + 1) * block_e * 4 + 2 * width * bin_n * 4
+            + 3 * bin_n * block_e * 4 + bin_n * 128 * 4)
 
 
-def _kernel(x_ref, active_ref, src_ref, dst_ref, w_ref, ptr_ref, acc_ref,
-            *, n: int, bin_n: int, combine: str, msg: str, block_e: int,
-            passes: int, strategy: str):
+def _kernel(m_ref, dst_ref, acc_ref, *, n: int, bin_n: int, combine: str,
+            mxu: bool):
+    # m_ref: [B, block_e] messages (identity where masked); dst_ref:
+    # [1, block_e]; acc_ref: [B, bin_n], resident across the edge axis
     b = pl.program_id(0)
-    e = pl.program_id(1)
-    ident = combine_identity(combine, acc_ref.dtype)
+    dt = acc_ref.dtype
+    ident = combine_identity(combine, dt)
 
-    @pl.when(e == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        acc_ref[...] = jnp.full_like(acc_ref, ident)
+        acc_ref[...] = jnp.full(acc_ref.shape, ident, dt)
 
-    src = src_ref[0]
-    dst = dst_ref[0]
-    w = w_ref[0]
-    # sentinel-padded slots carry n on both endpoints: mask everything
-    valid = dst < n
-    safe_src = jnp.where(valid, src, 0)
-    x = x_ref[safe_src]                       # [block_e(, B)]
-    act = active_ref[safe_src] > 0
-    if msg == "copy":
-        m_val = x
-    else:
-        wb = w[..., None] if x.ndim == 2 else w
-        m_val = x * wb if msg == "mul" else x + wb
-    ok = valid & act
-    if m_val.ndim == 2:
-        ok = ok[:, None]
-    m_val = jnp.where(ok, m_val.astype(acc_ref.dtype), ident)
+    dst = dst_ref[...]
+    block_e = dst.shape[-1]
     # bin-relative destination; padding gets the one-past row bin_n so
-    # it can never merge into (or one-hot onto) a real destination's run
-    rel = jnp.where(valid, dst - b * bin_n, bin_n)
-
-    if strategy == "mxu":
-        in_bin = rel < bin_n
-        relc = jnp.clip(rel, 0, bin_n - 1)
-        if combine == "sum" and jnp.issubdtype(acc_ref.dtype,
-                                               jnp.floating):
-            # CRCW-CB combine on the MXU: one-hot matmul
-            onehot = ((relc[None, :] == jnp.arange(bin_n)[:, None])
-                      & in_bin[None, :]).astype(acc_ref.dtype)
-            local = onehot @ m_val            # [bin_n(, B)]
-        else:
-            sel = ((relc[None, :] == jnp.arange(bin_n)[:, None])
-                   & in_bin[None, :])          # [bin_n, block_e]
-            if m_val.ndim == 2:
-                sel = sel[..., None]
-            expanded = jnp.where(sel, m_val[None, ...], ident)
-            if combine == "sum":
-                # segment_sum accumulates in the message dtype
-                local = expanded.sum(axis=1).astype(acc_ref.dtype)
-            elif combine == "max":
-                local = expanded.max(axis=1)
-            else:
-                local = expanded.min(axis=1)
+    # it can never one-hot onto a real destination
+    rel = jnp.where(dst < np.int32(n), dst - b * np.int32(bin_n),
+                    np.int32(bin_n))
+    hit = lax.broadcasted_iota(jnp.int32, (bin_n, block_e), 0) == rel
+    msgs = m_ref[...]
+    if mxu:
+        # CRCW-CB combine on the MXU: [B, block_e] x [bin_n, block_e]^T
+        local = lax.dot_general(
+            msgs, hit.astype(dt), (((1,), (1,)), ((), ())),
+            precision=lax.Precision.HIGHEST,
+            preferred_element_type=dt)
+        acc_ref[...] += local
+        return
+    rows = []
+    for j in range(msgs.shape[0]):
+        win = jnp.where(hit, msgs[j:j + 1, :], ident)
+        col = reduce_keep(win, combine, axis=1)            # [bin_n, 1]
+        # column -> lane-major row through one aligned tile transpose
+        rows.append(jnp.transpose(
+            jnp.broadcast_to(col, (bin_n, 128)))[0:1, :])
+    local = rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
+    acc = acc_ref[...]
+    if combine == "sum":
+        acc_ref[...] = acc + local
+    elif combine == "max":
+        acc_ref[...] = jnp.maximum(acc, local)
     else:
-        # "scan": run boundaries from the plan's per-bin row pointer,
-        # rebased to this edge chunk
-        ptr = ptr_ref[0]
-        base = e * block_e
-        lo = jnp.clip(ptr[:-1] - base, 0, block_e)   # [bin_n]
-        hi = jnp.clip(ptr[1:] - base, 0, block_e)
-        if combine == "sum":
-            # prefix-sum difference: O(block_e + bin_n). Floats carry
-            # the prefix in f64 so cs[hi] - cs[lo] never cancels below
-            # the parity tolerance; ints are exact under wraparound.
-            acc_dt = (jnp.float64
-                      if jnp.issubdtype(acc_ref.dtype, jnp.floating)
-                      else acc_ref.dtype)
-            cs = jnp.cumsum(m_val.astype(acc_dt), axis=0)
-            cs = jnp.concatenate(
-                [jnp.zeros((1,) + cs.shape[1:], cs.dtype), cs], axis=0)
-            local = (cs[hi] - cs[lo]).astype(acc_ref.dtype)
-        else:
-            # segmented Hillis-Steele min/max scan over dst runs:
-            # passes = ceil(log2(longest run within a chunk))
-            y = m_val
-            for s in (1 << k for k in range(passes)):
-                if s >= block_e:
-                    break
-                same = rel[s:] == rel[:-s]
-                if y.ndim == 2:
-                    same = same[:, None]
-                red = (jnp.minimum(y[s:], y[:-s]) if combine == "min"
-                       else jnp.maximum(y[s:], y[:-s]))
-                y = jnp.concatenate([y[:s], jnp.where(same, red, y[s:])],
-                                    axis=0)
-            last = jnp.clip(hi - 1, 0, block_e - 1)
-            got = y[last]                     # run tails, one per dst
-            nonempty = hi > lo
-            if got.ndim == 2:
-                nonempty = nonempty[:, None]
-            local = jnp.where(nonempty, got, ident)
-    acc_ref[...] = _acc_combine(acc_ref[...], local, combine)
-
-
-def _scan_passes(max_run: int, block_e: int) -> int:
-    span = max(2, min(max_run, block_e))
-    return max(1, math.ceil(math.log2(span)))
+        acc_ref[...] = jnp.minimum(acc, local)
 
 
 @functools.partial(jax.jit,
@@ -337,8 +248,7 @@ def coo_push_pallas(x: jax.Array, active: jax.Array, src: jax.Array,
     if interpret is None:
         interpret = default_interpret()
     m = src.shape[0]
-    out_dtype = (x.dtype if msg == "copy"
-                 else jnp.result_type(x.dtype, w.dtype))
+    out_dtype = msg_dtype(x.dtype, w.dtype, msg)
     if m == 0:
         # edgeless graph: no edges means every destination holds the
         # combine identity, like the segment primitives
@@ -356,33 +266,42 @@ def coo_push_pallas(x: jax.Array, active: jax.Array, src: jax.Array,
         raise ValueError(
             f"plan cap={cap} not a multiple of block_e={block_e}: "
             "build the plan with align=block_e")
-    passes = _scan_passes(plan.max_run, block_e)
-    grid = (nb, cap // block_e)
-    n_pad = nb * bin_n
+    check_compiled("coo_push_pallas", interpret,
+                   dtypes=(x.dtype, out_dtype),
+                   lane_blocks=((block_e, cap), (bin_n, None)))
+    # phase 2, XLA side: per-edge messages in the plan layout, masked
+    # to the combine identity off the frontier and on padding slots
+    valid = plan.dst < n
+    safe = jnp.where(valid, plan.src, 0)
+    vals = jnp.take(x, safe, axis=0, mode="clip")      # [nb, cap(, B)]
+    if msg != "copy":
+        wb = plan.w[..., None] if x.ndim == 2 else plan.w
+        vals = vals * wb if msg == "mul" else vals + wb
+    ok = valid & jnp.take(active, safe, axis=0, mode="clip")
     if x.ndim == 2:
-        b_width = x.shape[1]
-        acc_spec = pl.BlockSpec((bin_n, b_width), lambda b, e: (b, 0))
-        acc_shape = jax.ShapeDtypeStruct((n_pad, b_width), out_dtype)
-        x_spec = pl.BlockSpec(x.shape, lambda b, e: (0, 0))
-    else:
-        acc_spec = pl.BlockSpec((bin_n,), lambda b, e: (b,))
-        acc_shape = jax.ShapeDtypeStruct((n_pad,), out_dtype)
-        x_spec = pl.BlockSpec(x.shape, lambda b, e: (0,))
+        ok = ok[..., None]
+    msgs = jnp.where(ok, vals.astype(out_dtype),
+                     combine_identity(combine, out_dtype))
+    msgs = msgs[:, None, :] if x.ndim == 1 else jnp.swapaxes(msgs, 1, 2)
+    width = msgs.shape[1]
+    mxu = (strategy == "mxu" and combine == "sum"
+           and jnp.issubdtype(out_dtype, jnp.floating))
     acc = pl.pallas_call(
         functools.partial(_kernel, n=n, bin_n=bin_n, combine=combine,
-                          msg=msg, block_e=block_e, passes=passes,
-                          strategy=strategy),
-        grid=grid,
+                          mxu=mxu),
+        grid=(nb, cap // block_e),
         in_specs=[
-            x_spec,
-            pl.BlockSpec(active.shape, lambda b, e: (0,)),
-            pl.BlockSpec((1, block_e), lambda b, e: (b, e)),
-            pl.BlockSpec((1, block_e), lambda b, e: (b, e)),
-            pl.BlockSpec((1, block_e), lambda b, e: (b, e)),
-            pl.BlockSpec((1, bin_n + 1), lambda b, e: (b, 0)),
+            pl.BlockSpec((None, width, block_e), lambda b, e: (b, Z, e)),
+            pl.BlockSpec((None, 1, block_e), lambda b, e: (b, Z, e)),
         ],
-        out_specs=acc_spec,
-        out_shape=acc_shape,
+        out_specs=pl.BlockSpec((None, width, bin_n),
+                               lambda b, e: (b, Z, Z)),
+        out_shape=jax.ShapeDtypeStruct((nb, width, bin_n), out_dtype),
+        compiler_params=compiler_params(
+            interpret, ("parallel", "arbitrary"),
+            push_vmem_bytes(block_e, bin_n, width)),
         interpret=interpret,
-    )(x, active.astype(jnp.int32), plan.src, plan.dst, plan.w, plan.ptr)
-    return acc[:n]
+    )(msgs, plan.dst[:, None, :])
+    # [nb, B, bin_n] -> [n(, B)]
+    acc = jnp.swapaxes(acc, 0, 1).reshape(width, nb * bin_n)[:, :n]
+    return acc[0] if x.ndim == 1 else acc.T

@@ -15,16 +15,17 @@ the TPU layout:
 
 ``rows`` is the *compacted* touched-destination id list (sentinel ``n``
 in padding slots — see :func:`frontier_rows`). The grid tiles ``rows``,
-not the vertex range: each step gathers its row ids, then the ELL rows
-of those ids, then the payloads of those neighbors — three levels of
-irregular read that all stay inside the tile, while writes remain
-private per touched row (the pull property, unchanged). Work is
-``R_pad × d_ell`` instead of ``n × d_ell``: at a 10% frontier the
-kernel does a tenth of the full scan's gathers.
+not the vertex range: XLA gathers the ELL-in rows of those ids (a row
+gather of contiguous ``d_ell``-wide slices), and the rectangular
+``ell_spmv_pallas`` kernel then gathers and combines their neighbors'
+payloads — writes remain private per touched row (the pull property,
+unchanged). Work is ``R_pad × d_ell`` instead of ``n × d_ell``: at a
+10% frontier the kernel does a tenth of the full scan's gathers.
 
 Coverage matches ``ell_spmv_pallas`` exactly — combine ∈
-{sum, max, min}, payloads ``[n]``/``[n, B]``, float32/float64/int32/
-int64, msg ∈ {copy, mul, add} — and untouched rows come back as the
+{sum, max, min}, payloads ``[n]``/``[n, B]``, 32-bit payloads compiled
+(any width interpreted), msg ∈ {copy, mul, add} — and untouched rows
+come back as the
 combine identity, so the full-vector result
 (:func:`ell_pull_frontier_full`) equals
 ``mask_untouched(ell_spmv_pallas(...), touched)``: bit-identical for
@@ -40,10 +41,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from ..core.primitives import combine_identity
-from .ell_spmv import _apply_msg, _out_dtype, default_interpret
+from .ell_spmv import _out_dtype, ell_spmv_pallas
 
 __all__ = ["ell_pull_frontier_pallas", "ell_pull_frontier_full",
            "frontier_rows", "default_pull_cap"]
@@ -74,36 +74,18 @@ def frontier_rows(touched: jax.Array, size: int) -> jax.Array:
     dropped — callers guard with a fits bit (count ≤ size) before
     trusting the compaction."""
     n = touched.shape[0]
-    rows = jnp.nonzero(touched, size=size, fill_value=n)[0]
-    return rows.astype(jnp.int32)
-
-
-def _kernel(rows_ref, x_ref, idx_ref, w_ref, out_ref, *, combine: str,
-            msg: str, n: int):
-    # rows_ref: [block_r] streamed tile of touched row ids; idx_ref /
-    # w_ref: the full [n, d_ell] ELL-in matrices resident in ANY;
-    # x_ref: the full padded payload. Three-level gather: row ids ->
-    # ELL rows -> neighbor payloads, all inside the tile.
-    rows = rows_ref[...]
-    live = rows < n
-    safe_rows = jnp.where(live, rows, 0)
-    idx = idx_ref[safe_rows]                 # [block_r, d_ell]
-    w = w_ref[safe_rows]
-    valid = live[:, None] & (idx < n)
-    safe = jnp.where(valid, idx, 0)
-    gathered = x_ref[safe]                   # [block_r, d_ell(, B)]
-    msgs = _apply_msg(gathered, w, msg)
-    ident = combine_identity(combine, msgs.dtype)
-    if msgs.ndim == 3:
-        valid = valid[..., None]
-    masked = jnp.where(valid, msgs, ident)
-    if combine == "sum":
-        out = masked.sum(axis=1)
-    elif combine == "max":
-        out = masked.max(axis=1)
-    else:
-        out = masked.min(axis=1)
-    out_ref[...] = out.astype(out_ref.dtype)
+    # rank of each touched row by a two-level prefix sum (within 1024-
+    # wide chunks, then over chunk totals), then one scatter. Equal to
+    # jnp.nonzero(size=...), which the TPU compiler takes minutes over
+    # at millions of rows.
+    chunk = 1024
+    x = jnp.pad(touched, (0, -n % chunk)).astype(jnp.int32)
+    inner = jnp.cumsum(x.reshape(-1, chunk), axis=1)
+    outer = jnp.cumsum(inner[:, -1]) - inner[:, -1]
+    rank = (inner + outer[:, None]).reshape(-1)[:n] - 1
+    return jnp.full((size,), n, jnp.int32).at[
+        jnp.where(touched, rank, size)].set(
+            jnp.arange(n, dtype=jnp.int32), mode="drop")
 
 
 @functools.partial(jax.jit,
@@ -124,39 +106,18 @@ def ell_pull_frontier_pallas(x_padded: jax.Array, ell_idx: jax.Array,
     with ``rows``; sentinel slots hold the combine identity. Use
     :func:`ell_pull_frontier_full` for the scattered full-vector form.
     """
-    if interpret is None:
-        interpret = default_interpret()
-    n, d_ell = ell_idx.shape
+    n = ell_idx.shape[0]
     n_src = n if num_sources is None else num_sources
-    batched = x_padded.ndim == 2
-    (r,) = rows.shape
-    r_pad = _round_up(max(r, 1), block_r)
-    rows = jnp.pad(rows, (0, r_pad - r), constant_values=n_src)
-    grid = (r_pad // block_r,)
-    out_dtype = _out_dtype(x_padded.dtype, ell_w.dtype, msg, combine)
-    if batched:
-        b = x_padded.shape[1]
-        out_spec = pl.BlockSpec((block_r, b), lambda i: (i, 0))
-        out_shape = jax.ShapeDtypeStruct((r_pad, b), out_dtype)
-        x_spec = pl.BlockSpec(x_padded.shape, lambda i: (0, 0))
-    else:
-        out_spec = pl.BlockSpec((block_r,), lambda i: (i,))
-        out_shape = jax.ShapeDtypeStruct((r_pad,), out_dtype)
-        x_spec = pl.BlockSpec(x_padded.shape, lambda i: (0,))
-    out = pl.pallas_call(
-        functools.partial(_kernel, combine=combine, msg=msg, n=n_src),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_r,), lambda i: (i,)),   # row-id tile
-            x_spec,                                     # full payload
-            pl.BlockSpec(ell_idx.shape, lambda i: (0, 0)),
-            pl.BlockSpec(ell_w.shape, lambda i: (0, 0)),
-        ],
-        out_specs=out_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(rows, x_padded, ell_idx, ell_w)
-    return out[:r]
+    live = rows < n
+    safe = jnp.where(live, rows, 0)
+    # row gather of the touched ELL-in rows; dead slots read as all-
+    # sentinel rows, so the kernel returns the combine identity there
+    idx = jnp.where(live[:, None], jnp.take(ell_idx, safe, axis=0),
+                    n_src)
+    w = jnp.take(ell_w, safe, axis=0)
+    return ell_spmv_pallas(x_padded, idx, w, combine=combine, msg=msg,
+                           block_n=block_r, interpret=interpret,
+                           num_sources=n_src)
 
 
 @functools.partial(jax.jit,

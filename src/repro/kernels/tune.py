@@ -25,7 +25,16 @@ abandoned — the rest of its rungs only move block_e, which never
 recovers that much.
 
 Probing is eager and runs in a single worker thread so it escapes any
-ambient jit trace (the backend discovers new shapes mid-trace).
+ambient jit trace (the backend discovers new shapes mid-trace). Probes
+run at the real shape up to ``_PROBE_N`` vertices and at a scaled-down
+copy of it beyond (the winner is still cached under the real shape):
+the kernels' per-block cost does not depend on the grid length, and a
+full-size probe of every candidate would cost more than it saves.
+
+Compiled (TPU) candidates are restricted to tile-legal blocks whose
+working set fits ``VMEM_CAP``. A compiled probe that fails for a reason
+other than an injected fault or a deadline raises: degrading to the
+default would hide a kernel the chip refuses.
 """
 
 from __future__ import annotations
@@ -39,8 +48,9 @@ import jax
 import jax.numpy as jnp
 
 from ..resilience import FaultInjected, ProbeTimeout, fault_point, note
-from .coo_push import build_push_plan, coo_push_pallas
-from .ell_spmv import default_interpret, ell_spmv_pallas
+from .coo_push import build_push_plan, coo_push_pallas, push_vmem_bytes
+from .ell_spmv import (VMEM_CAP, default_interpret, ell_spmv_pallas,
+                       ell_vmem_bytes)
 
 __all__ = ["pull_candidates", "pull_frontier_candidates",
            "push_candidates", "tune_pull", "tune_pull_frontier",
@@ -50,54 +60,86 @@ _PULL_LADDER = (128, 256, 512, 1024, 2048, 4096)
 _EDGE_LADDER = (1024, 4096, 16384)
 _BIN_LADDER = (128, 256, 1024)
 _PRUNE = 2.0
+_PROBE_N = 1 << 16
 
 
 def _round_up(x: int, q: int) -> int:
     return -(-x // q) * q
 
 
-def pull_candidates(n: int, width: int | None = None) -> tuple[int, ...]:
+def _row_rungs(rows: int, width: int, d_ell: int | None,
+               compiled: bool) -> list[int]:
+    """Pull ladder rungs below the padded row count; compiled, only the
+    rungs whose working set fits ``VMEM_CAP`` (the ladder itself is
+    lane-aligned)."""
+    r_pad = _round_up(max(rows, 8), 8)
+    cands = [c for c in _PULL_LADDER if c < r_pad]
+    if compiled and d_ell is not None:
+        cands = [c for c in cands
+                 if ell_vmem_bytes(c, d_ell, width) <= VMEM_CAP]
+    return cands
+
+
+def _whole_rung_fits(rows: int, width: int, d_ell: int | None,
+                     compiled: bool) -> bool:
+    return not (compiled and d_ell is not None and ell_vmem_bytes(
+        _round_up(max(rows, 8), 8), d_ell, width) > VMEM_CAP)
+
+
+def pull_candidates(n: int, width: int | None = None, *,
+                    d_ell: int | None = None,
+                    compiled: bool = False) -> tuple[int, ...]:
     """``block_n`` rungs for the ELL pull kernel: the ladder below n
     plus the whole (padded) vertex range. Single-column payloads
     (``width == 1``) drop the full-row rung whenever sub-n rungs
     exist — the b1 gather is too thin to amortize a grid of one, and
     the full-row rung measurably loses to jnp there (the
     kernel_pull_*_b1 regression)."""
-    n_pad = _round_up(max(n, 8), 8)
-    cands = [c for c in _PULL_LADDER if c < n_pad]
-    if not (width == 1 and cands):
-        cands.append(n_pad)
+    cands = _row_rungs(n, width or 1, d_ell, compiled)
+    if not (width == 1 and cands) and (
+            not cands or _whole_rung_fits(n, width or 1, d_ell, compiled)):
+        cands.append(_round_up(max(n, 8), 8))
     return tuple(cands)
 
 
-def pull_frontier_candidates(n: int, rows: int) -> tuple[int, ...]:
+def pull_frontier_candidates(n: int, rows: int, *, width: int = 1,
+                             d_ell: int | None = None,
+                             compiled: bool = False) -> tuple[int, ...]:
     """``block_r`` rungs for the frontier pull kernel, keyed by frontier
     density: the grid tiles the compacted ``rows`` touched-row list (not
     the vertex range), so the useful rungs shrink with ``rows / n``. A
     sparse frontier (few hundred rows) wants one or two tiles; only
     near-full frontiers see the deep ladder. Rungs are the pull ladder
     clipped below the padded row count, plus the whole-range rung."""
-    r_pad = _round_up(max(rows, 8), 8)
-    cands = [c for c in _PULL_LADDER if c < r_pad]
-    cands.append(r_pad)
+    cands = _row_rungs(rows, width, d_ell, compiled)
+    if not cands or _whole_rung_fits(rows, width, d_ell, compiled):
+        cands.append(_round_up(max(rows, 8), 8))
     return tuple(cands)
 
 
-def push_candidates(n: int, m: int) -> tuple[tuple[int, int, str], ...]:
+def push_candidates(n: int, m: int, *, width: int = 1,
+                    compiled: bool = False
+                    ) -> tuple[tuple[int, int, str], ...]:
     """(block_e, block_n, strategy) grid for the two-phase push kernel.
 
     ``block_n`` is the destination-bin width (phase 1), ``block_e`` the
     streamed edge-chunk size (phase 2), ``strategy`` the reduce. Scan
     rungs cover the full bin ladder; MXU rungs are limited to bins the
     window/one-hot expansion can afford (its work is bin_n × cap).
-    Ordered scan-first so pruning meets the incumbent early.
+    Ordered scan-first so pruning meets the incumbent early. Compiled,
+    bins and edge blocks are lane multiples whose working set fits
+    ``VMEM_CAP`` (the whole-range bin never does at real sizes).
     """
-    n_pad = _round_up(max(n, 8), 8)
-    m_pad = _round_up(max(m, 8), 8)
+    q = 128 if compiled else 8
+    n_pad = _round_up(max(n, 8), q)
+    m_pad = _round_up(max(m, 8), q)
     bins = sorted({min(b, n_pad) for b in _BIN_LADDER} | {n_pad})
     edges = sorted({min(e, m_pad) for e in _EDGE_LADDER} | {m_pad})
     cands = [(e, b, "scan") for b in bins for e in edges]
     cands += [(e, b, "mxu") for b in bins if b <= 256 for e in edges]
+    if compiled:
+        cands = [c for c in cands
+                 if push_vmem_bytes(c[0], c[1], width) <= VMEM_CAP]
     return tuple(cands)
 
 
@@ -257,11 +299,13 @@ def _probe_retries() -> int:
     return int(os.environ.get("REPRO_TUNE_RETRIES", "2"))
 
 
-def _probe_guarded(kernel: str, probe, default):
+def _probe_guarded(kernel: str, probe, default, strict: bool = False):
     """Run ``probe`` off-thread under the wall deadline with bounded
     retry-with-backoff; returns ``(winner, probed)``. Exhausted
     attempts degrade to ``default`` (``probed=False`` — the caller must
-    NOT persist it, so a healthy later run re-probes)."""
+    NOT persist it, so a healthy later run re-probes). ``strict``
+    (compiled kernels) re-raises any failure that is neither an
+    injected fault nor a deadline."""
     deadline, retries = _probe_deadline_s(), _probe_retries()
 
     def attempt_fn():
@@ -273,6 +317,8 @@ def _probe_guarded(kernel: str, probe, default):
             return _escaped(attempt_fn, deadline=deadline,
                             kernel=kernel), True
         except Exception as e:   # noqa: BLE001 — chaos/flake seam
+            if strict and not isinstance(e, (FaultInjected, ProbeTimeout)):
+                raise
             timed_out = isinstance(e, ProbeTimeout)
             with _LOCK:
                 _STATS["probe_timeouts" if timed_out
@@ -295,7 +341,8 @@ def tune_pull(n: int, d_ell: int, width: int, dtype, combine: str,
     shape-and-platform-keyed, persisted)."""
     if interpret is None:
         interpret = default_interpret()
-    cands = pull_candidates(n, width)
+    cands = pull_candidates(n, width, d_ell=d_ell,
+                            compiled=not interpret)
     if len(cands) == 1:                   # nothing to probe
         return cands[0]
     key = _cache_key("pull", interpret, (n, d_ell), width, dtype,
@@ -308,10 +355,12 @@ def tune_pull(n: int, d_ell: int, width: int, dtype, combine: str,
             pass   # poisoned cache entry: fall through and re-probe
 
     def probe():
+        n_p = min(n, _PROBE_N)
         key_ = jax.random.PRNGKey(0)
-        idx = jax.random.randint(key_, (n, d_ell), 0, n + 1, jnp.int32)
-        w = jnp.ones((n, d_ell), jnp.float32)
-        shape = (n + 1,) if width == 1 else (n + 1, width)
+        idx = jax.random.randint(key_, (n_p, d_ell), 0, n_p + 1,
+                                 jnp.int32)
+        w = jnp.ones((n_p, d_ell), jnp.float32)
+        shape = (n_p + 1,) if width == 1 else (n_p + 1, width)
         x = jnp.ones(shape, dtype)
         best, best_t = None, None
         for block_n in cands:
@@ -324,7 +373,8 @@ def tune_pull(n: int, d_ell: int, width: int, dtype, combine: str,
 
     with _LOCK:
         _STATS["probes"] += 1
-    best, probed = _probe_guarded("pull", probe, cands[0])
+    best, probed = _probe_guarded("pull", probe, cands[0],
+                                  strict=not interpret)
     if probed:
         _cache_put(key, best)
     return best
@@ -339,7 +389,8 @@ def tune_pull_frontier(n: int, d_ell: int, rows: int, width: int, dtype,
     graph want different tiles, so they tune — and cache — separately."""
     if interpret is None:
         interpret = default_interpret()
-    cands = pull_frontier_candidates(n, rows)
+    cands = pull_frontier_candidates(n, rows, width=width, d_ell=d_ell,
+                                     compiled=not interpret)
     if len(cands) == 1:
         return cands[0]
     key = _cache_key("pullf", interpret, (n, d_ell, rows), width, dtype,
@@ -353,14 +404,18 @@ def tune_pull_frontier(n: int, d_ell: int, rows: int, width: int, dtype,
 
     def probe():
         from .ell_pull_frontier import ell_pull_frontier_pallas
+        n_p = min(n, _PROBE_N)
+        rows_p = rows if n_p == n else max(8, rows * n_p // n)
         key_ = jax.random.PRNGKey(2)
-        idx = jax.random.randint(key_, (n, d_ell), 0, n + 1, jnp.int32)
-        w = jnp.ones((n, d_ell), jnp.float32)
-        shape = (n + 1,) if width == 1 else (n + 1, width)
+        idx = jax.random.randint(key_, (n_p, d_ell), 0, n_p + 1,
+                                 jnp.int32)
+        w = jnp.ones((n_p, d_ell), jnp.float32)
+        shape = (n_p + 1,) if width == 1 else (n_p + 1, width)
         x = jnp.ones(shape, dtype)
         rids = jax.random.permutation(
-            jax.random.fold_in(key_, 1), n)[:rows].astype(jnp.int32)
-        rids = jnp.pad(rids, (0, max(0, rows - n)), constant_values=n)
+            jax.random.fold_in(key_, 1), n_p)[:rows_p].astype(jnp.int32)
+        rids = jnp.pad(rids, (0, max(0, rows_p - n_p)),
+                       constant_values=n_p)
         best, best_t = None, None
         for block_r in cands:
             t = _time(lambda b=block_r: ell_pull_frontier_pallas(
@@ -372,7 +427,8 @@ def tune_pull_frontier(n: int, d_ell: int, rows: int, width: int, dtype,
 
     with _LOCK:
         _STATS["probes"] += 1
-    best, probed = _probe_guarded("pullf", probe, cands[0])
+    best, probed = _probe_guarded("pullf", probe, cands[0],
+                                  strict=not interpret)
     if probed:
         _cache_put(key, best)
     return best
@@ -386,7 +442,7 @@ def tune_push(n: int, m: int, width: int, dtype, combine: str,
     keyed, persisted to the on-disk cache."""
     if interpret is None:
         interpret = default_interpret()
-    cands = push_candidates(n, m)
+    cands = push_candidates(n, m, width=width, compiled=not interpret)
     if len(cands) == 1:
         return cands[0]
     key = _cache_key("push", interpret, (n, m), width, dtype, combine,
@@ -400,14 +456,17 @@ def tune_push(n: int, m: int, width: int, dtype, combine: str,
             pass   # poisoned cache entry: fall through and re-probe
 
     def probe():
+        n_p = min(n, _PROBE_N)
+        m_p = max(1, m * n_p // n)
         key_ = jax.random.PRNGKey(1)
-        dst = jnp.sort(jax.random.randint(key_, (m,), 0, n, jnp.int32))
-        src = jax.random.randint(jax.random.fold_in(key_, 1), (m,), 0,
-                                 n, jnp.int32)
-        w = jnp.ones((m,), jnp.float32)
-        shape = (n,) if width == 1 else (n, width)
+        dst = jnp.sort(jax.random.randint(key_, (m_p,), 0, n_p,
+                                          jnp.int32))
+        src = jax.random.randint(jax.random.fold_in(key_, 1), (m_p,), 0,
+                                 n_p, jnp.int32)
+        w = jnp.ones((m_p,), jnp.float32)
+        shape = (n_p,) if width == 1 else (n_p, width)
         x = jnp.ones(shape, dtype)
-        active = jnp.ones((n,), bool)
+        active = jnp.ones((n_p,), bool)
         plans: dict[tuple[int, int], object] = {}
         best, best_t = None, None
         pruned: set[tuple[str, int]] = set()
@@ -418,11 +477,11 @@ def tune_push(n: int, m: int, width: int, dtype, combine: str,
                 continue
             pkey = (block_n, block_e)
             if pkey not in plans:
-                plans[pkey] = build_push_plan(src, dst, w, n, block_n,
+                plans[pkey] = build_push_plan(src, dst, w, n_p, block_n,
                                               align=block_e)
             t = _time(lambda be=block_e, bn=block_n, st=strategy,
                       p=plans[pkey]: coo_push_pallas(
-                x, active, src, dst, w, n, combine=combine, msg=msg,
+                x, active, src, dst, w, n_p, combine=combine, msg=msg,
                 block_e=be, block_n=bn, interpret=interpret, plan=p,
                 strategy=st))
             first = group not in group_seen
@@ -436,7 +495,8 @@ def tune_push(n: int, m: int, width: int, dtype, combine: str,
 
     with _LOCK:
         _STATS["probes"] += 1
-    best, probed = _probe_guarded("push", probe, cands[0])
+    best, probed = _probe_guarded("push", probe, cands[0],
+                                  strict=not interpret)
     if probed:
         _cache_put(key, best)
     return best

@@ -1,8 +1,11 @@
 """Three-term roofline from compiled dry-run artifacts (TPU v5e target).
 
-    compute term    = HLO_FLOPs / (chips × 197e12 FLOP/s bf16)
-    memory term     = HLO_bytes / (chips × 819e9 B/s HBM)
-    collective term = collective_bytes / (chips × 50e9 B/s ICI link)
+    compute term    = HLO_FLOPs / (chips × peak FLOP/s bf16)
+    memory term     = HLO_bytes / (chips × peak HBM B/s)
+    collective term = collective_bytes / (chips × ICI B/s per link)
+
+The peaks come from one table keyed by ``device_kind`` (as JAX reports
+it); a device that is not in the table is an error, never a default.
 
 `cost_analysis()` supplies FLOPs/bytes (already per-partition under SPMD);
 collective bytes come from parsing the compiled HLO: we sum the *output*
@@ -14,14 +17,28 @@ from __future__ import annotations
 
 import re
 
-__all__ = ["HW", "collective_bytes_from_hlo", "roofline_report",
-           "model_flops", "kernel_roofline"]
+__all__ = ["PEAKS", "V5E", "peaks", "collective_bytes_from_hlo",
+           "roofline_report", "model_flops", "kernel_roofline"]
 
-HW = {
-    "peak_flops": 197e12,     # bf16 per chip
-    "hbm_bw": 819e9,          # bytes/s per chip
-    "ici_bw": 50e9,           # bytes/s per link (~per chip per direction)
+V5E = "TPU v5 lite"       # jax.devices()[0].device_kind of a v5e chip
+
+# Published per-chip peaks. TPU v5e: Google Cloud documentation, "TPU
+# v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# inter-chip interconnect over 4 links (50 GB/s per link).
+PEAKS = {
+    V5E: {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
 }
+
+
+def peaks(device_kind: str) -> dict:
+    """The peak table's row for ``device_kind``; raises for a device
+    whose published peaks are not in the table."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -85,9 +102,9 @@ def model_flops(kind: str, **kw) -> float:
     return mult * n_active * tokens
 
 
-def kernel_roofline(direction: str, *, n: int, d_ell: int = 0,
-                    batch: int = 1, itemsize: int = 4, nb: int = 1,
-                    cap: int = 0, bin_n: int = 0,
+def kernel_roofline(direction: str, *, device_kind: str, n: int,
+                    d_ell: int = 0, batch: int = 1, itemsize: int = 4,
+                    nb: int = 1, cap: int = 0, bin_n: int = 0,
                     measured_us: float = 0.0) -> dict:
     """Analytic roofline bound for one graph-kernel launch.
 
@@ -100,8 +117,9 @@ def kernel_roofline(direction: str, *, n: int, d_ell: int = 0,
     layout); ``pullf`` the frontier-restricted gather over ``rows``
     compacted destinations (pass the padded row capacity as ``n`` —
     only those ELL rows are read and written); ``push`` is the
-    two-phase bin reduce (``nb × cap`` padded edge bins + per-bin run
-    pointers + ``nb × bin_n`` accumulators). The ratio is clamped to
+    two-phase bin reduce (``nb × cap`` padded edge bins + ``nb ×
+    bin_n`` accumulators), priced against ``device_kind``'s peaks.
+    The ratio is clamped to
     the schema's 1.5 ceiling — anything past ~1.0 means timing noise,
     not physics.
     """
@@ -115,19 +133,21 @@ def kernel_roofline(direction: str, *, n: int, d_ell: int = 0,
     else:
         bytes_moved = (nb * cap * (4 + 4 + 4)           # src / dst / w
                        + nb * cap * batch * itemsize    # payload gather
-                       + nb * (bin_n + 1) * 4           # run pointers
                        + nb * bin_n * batch * itemsize)  # accumulators
         flops = nb * cap * batch
-    bound_us = 1e6 * max(flops / HW["peak_flops"],
-                         bytes_moved / HW["hbm_bw"])
+    hw = peaks(device_kind)
+    bound_us = 1e6 * max(flops / hw["peak_flops"],
+                         bytes_moved / hw["hbm_bw"])
     return {"bytes_moved": int(bytes_moved), "flops": int(flops),
             "bound_us": bound_us,
             "pct_roofline": min(bound_us / max(measured_us, 1e-9), 1.5)}
 
 
-def roofline_report(result: dict, loop_factor: int = 1) -> dict:
+def roofline_report(result: dict, loop_factor: int = 1,
+                    device_kind: str = V5E) -> dict:
     """Attach the three terms (seconds) + dominant bottleneck to a dry-run
-    result dict (cost analysis is per-partition under SPMD).
+    result dict (cost analysis is per-partition under SPMD), priced
+    against ``device_kind`` — the dry-run's v5e target by default.
 
     loop_factor: XLA's cost_analysis and the HLO text count a while-loop
     body ONCE, so a scan-over-layers model under-reports loop-resident
@@ -142,9 +162,10 @@ def roofline_report(result: dict, loop_factor: int = 1) -> dict:
     flops = (result["cost"]["flops"] or 0.0) * loop_factor
     bytes_acc = (result["cost"]["bytes_accessed"] or 0.0) * loop_factor
     coll_bytes = result["collectives"]["total_bytes"] * loop_factor
-    t_compute = flops / HW["peak_flops"]
-    t_memory = bytes_acc / HW["hbm_bw"]
-    t_coll = coll_bytes / HW["ici_bw"]
+    hw = peaks(device_kind)
+    t_compute = flops / hw["peak_flops"]
+    t_memory = bytes_acc / hw["hbm_bw"]
+    t_coll = coll_bytes / hw["ici_bw"]
     terms = {"compute_s": t_compute, "memory_s": t_memory,
              "collective_s": t_coll}
     dominant = max(terms, key=lambda k: terms[k])

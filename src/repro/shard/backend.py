@@ -31,6 +31,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..core.backend import ExchangeBackend
 from ..core.cost_model import Cost, counter, counter_dtype
@@ -94,7 +95,10 @@ class ShardedBackend(ExchangeBackend):
         if inner not in ("dense", "ell", "pallas"):
             raise ValueError(f"unknown inner executor {inner!r}")
         part = partition_1d(g.n, P)      # validates 1 <= P <= n
-        topo = build_topology(g, part)
+        # every view is [P, ...] grouped by owner shard: place row p on
+        # device p once, instead of moving it on every solve
+        topo = jax.device_put(build_topology(g, part),
+                              NamedSharding(mesh, PartitionSpec(axis)))
         return cls(mesh=mesh, topo=topo, axis=axis, inner=inner,
                    compression=compression, interpret=interpret)
 
@@ -161,6 +165,12 @@ class ShardedBackend(ExchangeBackend):
         width = 1 if values.ndim == 1 else values.shape[-1]
         item = values.dtype.itemsize * width
         return counter(npad * item * (Pn - 1) // max(Pn, 1)) * Pn
+
+    def operands(self):
+        return self.topo
+
+    def bind(self, operands) -> "ShardedBackend":
+        return dataclasses.replace(self, topo=operands)
 
     # -- exchange state (error-feedback carry) ----------------------------
     def init_exchange_state(self, g: Graph):

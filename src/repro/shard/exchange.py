@@ -101,7 +101,7 @@ def sharded_push(mesh: Mesh, topo: ShardTopology, values_pad: jax.Array,
 
     edge_spec = P(axis, None)
 
-    def body(vb, fb, ls, ld, lw, lok, rs, rd, rw, rok, eb):
+    def body(vb, fb, ls, ld, lw, lok, rs, rd, rw, rok, *eb):
         base = jax.lax.axis_index(axis) * shard
 
         def gather_side(sb, db, wb, okb, local):
@@ -117,12 +117,12 @@ def sharded_push(mesh: Mesh, topo: ShardTopology, values_pad: jax.Array,
         loc = gather_side(ls, ld, lw, lok, local=True)
         acc = gather_side(rs, rd, rw, rok, local=False)
 
-        new_err = eb
         if compressing:
-            dec, res = compress_tree(acc + eb.reshape(-1),
+            (e,) = eb
+            dec, res = compress_tree(acc + e.reshape(-1),
                                      jnp.zeros_like(acc), cfg)
             # error feedback: carry acc + err - sent forward
-            new_err = res.reshape(eb.shape)
+            new_err = res.reshape(e.shape)
             acc = dec
 
         if combine == "sum":
@@ -132,22 +132,20 @@ def sharded_push(mesh: Mesh, topo: ShardTopology, values_pad: jax.Array,
             red = (jax.lax.pmin if combine == "min"
                    else jax.lax.pmax)(acc, axis)
             rem = jax.lax.dynamic_slice_in_dim(red, base, shard)
-        return merge_combine(combine, loc, rem), new_err
+        out = merge_combine(combine, loc, rem)
+        return (out, new_err) if compressing else out
 
-    in_specs = (P(axis), P(axis)) + (edge_spec,) * 8 + (edge_spec,)
-    out_specs = (P(axis), edge_spec)
-    if err is None:
-        # keep a uniform body signature; feed a zero-size dummy carry
-        err_in = jnp.zeros((part.num_parts, 0), jnp.float32)
-    else:
-        err_in = err
+    # the error carry enters and leaves the block only when compressing
+    extra = (edge_spec,) if compressing else ()
+    in_specs = (P(axis), P(axis)) + (edge_spec,) * 8 + extra
+    out_specs = (P(axis), edge_spec) if compressing else P(axis)
     block = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                           out_specs=out_specs, check_vma=False)
-    out, err_out = block(values_pad, frontier_pad,
-                         loc_e.src, loc_e.dst, loc_e.w, loc_e.valid,
-                         rem_e.src, rem_e.dst, rem_e.w, rem_e.valid,
-                         err_in)
-    return out, (err_out if err is not None else err)
+    res = block(values_pad, frontier_pad,
+                loc_e.src, loc_e.dst, loc_e.w, loc_e.valid,
+                rem_e.src, rem_e.dst, rem_e.w, rem_e.valid,
+                *((err,) if compressing else ()))
+    return res if compressing else (res, err)
 
 
 def sharded_pull(mesh: Mesh, topo: ShardTopology, values_pad: jax.Array,

@@ -134,7 +134,9 @@ from repro.dist.overlap import ring_allreduce_psum
 from jax.sharding import PartitionSpec as P
 import functools
 
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+from jax.sharding import AxisType
+AUTO = (AxisType.Auto,) * 2
+mesh = jax.make_mesh((8, 1), ("data", "model"), axis_types=AUTO)
 g = erdos_renyi(128, 4.0, seed=5, weighted=True)
 part = partition_1d(g.n, 8)
 local, remote, stats = pa_split(g, part)
@@ -181,7 +183,7 @@ assert ok_push and ok_pull
 import dataclasses
 from repro.models.moe import MoEConfig, moe_init, moe_apply, moe_apply_ep
 from repro.dist.sharding import set_activation_mesh
-mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+mesh2 = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO)
 cfg = MoEConfig(d_model=16, d_ff_expert=8, n_experts=8, top_k=2,
                 n_shared=1, capacity_factor=8.0, dispatch="pull")
 params = moe_init(jax.random.PRNGKey(0), cfg)
@@ -205,12 +207,14 @@ def test_dist_exchanges_multidevice():
     """shard_map exchanges need >1 device: run in a subprocess with 8
     fake host devices."""
     import os
+    from pathlib import Path
+    root = Path(__file__).resolve().parents[1]
     env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
+    env["PYTHONPATH"] = str(root / "src")
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", DIST_SCRIPT],
                        capture_output=True, text=True, timeout=300,
-                       env=env, cwd="/root/repo")
+                       env=env, cwd=str(root))
     assert "push_exchange ok: True" in r.stdout, r.stdout + r.stderr
     assert "pull_exchange ok: True" in r.stdout, r.stdout + r.stderr
     assert "ring==psum: True" in r.stdout, r.stdout + r.stderr

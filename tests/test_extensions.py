@@ -10,7 +10,8 @@ import pytest
 from repro.core.algorithms import pagerank, pagerank_delta, wcc
 from repro.core.direction import Direction, Fixed, GenericSwitch
 from repro.graphs import erdos_renyi, kronecker
-from repro.roofline.analysis import (collective_bytes_from_hlo,
+from repro.roofline.analysis import (V5E, collective_bytes_from_hlo,
+                                     kernel_roofline, peaks,
                                      roofline_report)
 
 
@@ -91,6 +92,40 @@ def test_roofline_report_terms():
     assert abs(rf["memory_s"] - 1.0) < 1e-6
     assert abs(rf["collective_s"] - 0.5) < 1e-6
     assert rf["dominant"] in ("compute", "memory")
+
+
+def test_roofline_peaks_refuse_unknown_devices():
+    """Peaks are looked up by the device kind JAX reports; a device
+    without published peaks is an error, never priced as a v5e."""
+    assert peaks(V5E)["hbm_bw"] == 819e9
+    for call in (lambda: peaks("cpu"),
+                 lambda: kernel_roofline("pull", device_kind="TPU v4",
+                                         n=1024, d_ell=8),
+                 lambda: roofline_report(
+                     {"cost": {"flops": 1.0, "bytes_accessed": 1.0},
+                      "collectives": {"total_bytes": 0}},
+                     device_kind="cpu")):
+        with pytest.raises(ValueError, match="no published peaks"):
+            call()
+
+
+def test_compile_cache_placement(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache
+    goes to the checkout's fixed .jax_cache directory."""
+    import jax
+    from repro.compile_cache import CHECKOUT, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = enable_compile_cache()
+        assert path == str(CHECKOUT / ".jax_cache")
+        assert (CHECKOUT / "src" / "repro").is_dir()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 # ---------------------------------------------------------- launchers ---

@@ -154,7 +154,8 @@ def test_push_bin_cap_guard_falls_back_correctly():
     """Traced path (the engine jits the graph): when the static bin
     capacity cannot hold the skewest bin, the lax.cond fits guard must
     route to the jnp branch and still produce the primitive's answer;
-    with enough capacity the same trace takes the kernel."""
+    with enough capacity the same trace takes the kernel. Each run of
+    the jnp branch is counted as ``fallback_push_overflow``."""
     # 16 edges all into dst 0: bin 0 holds 16 edges
     src = np.arange(16)
     dst = np.zeros(16, np.int64)
@@ -162,7 +163,7 @@ def test_push_bin_cap_guard_falls_back_correctly():
     x = jnp.arange(24, dtype=jnp.float32)
     act = jnp.ones((24,), bool)
     want, _ = push_relax(g, x, act)
-    for cap in (8, 32):  # 8 < 16 edges -> fallback; 32 -> kernel
+    for cap, overflows in ((8, 1), (32, 0)):  # 8 < 16 edges -> jnp
         backend = PallasBackend(block_e=8, push_block_n=8,
                                 push_strategy="scan", push_bin_cap=cap,
                                 autotune=False)
@@ -170,6 +171,8 @@ def test_push_bin_cap_guard_falls_back_correctly():
             g, v, f, "sum", None, Cost())[0])(g, x, act)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    rtol=1e-6)
+        jax.effects_barrier()
+        assert backend.stats["fallback_push_overflow"] == overflows
 
 
 def test_push_edgeless_bin_holds_combine_identity():
@@ -267,6 +270,72 @@ def test_unsupported_msg_fn_falls_back_to_ell_path(ragged_graph):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6)
     assert backend.stats["fallback_push"] == before["fallback_push"] + 1
+
+
+def test_compiled_backend_sends_64bit_payloads_to_counted_fallback(
+        ragged_graph):
+    """Compiled kernels take 32-bit payloads: a 64-bit cell is routed to
+    the jnp path up front and counted, never handed to Mosaic."""
+    g = ragged_graph
+    x = _payload(g, jnp.int64, None)
+    backend = PallasBackend(interpret=False)
+    got, _ = backend.pull(g, x, None, "min", None, Cost())
+    want, _ = EllBackend().pull(g, x, None, "min", None, Cost())
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    got, _ = backend.push(g, x, jnp.ones((g.n,), bool), "min", None,
+                          Cost())
+    want, _ = push_relax(g, x, jnp.ones((g.n,), bool), combine="min")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert backend.stats["fallback_pull"] == 1
+    assert backend.stats["fallback_push"] == 1
+    assert backend.stats["kernel_pull"] == backend.stats["kernel_push"] == 0
+
+
+@pytest.mark.parametrize("direction", ["pull", "push"])
+def test_kernel_failure_is_raised_not_served_from_jnp(ragged_graph,
+                                                      monkeypatch,
+                                                      direction):
+    """Only injected faults take the degradation ladder: a kernel that
+    fails for real propagates, so a chip run cannot time jnp and report
+    it as the kernel."""
+    g = ragged_graph
+    backend = PallasBackend()
+
+    def broken(*a, **k):
+        raise RuntimeError("Mosaic refused the kernel")
+
+    monkeypatch.setattr(PallasBackend, f"_{direction}_kernel", broken)
+    x = _payload(g, jnp.float32, None)
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        if direction == "pull":
+            backend.pull(g, x, None, "sum", None, Cost())
+        else:
+            backend.push(g, x, jnp.ones((g.n,), bool), "sum", None, Cost())
+    assert backend.stats[f"fault_fallback_{direction}"] == 0
+    assert backend.stats[f"fallback_{direction}"] == 0
+
+
+def test_compiled_tuner_candidates_are_tile_legal():
+    """Compiled ladders hold lane-aligned blocks whose working set fits
+    the per-kernel VMEM cap, even where the interpreted ladder offers
+    the whole vertex range."""
+    from repro.kernels.coo_push import push_vmem_bytes
+    from repro.kernels.ell_spmv import VMEM_CAP, ell_vmem_bytes
+    from repro.kernels.tune import pull_frontier_candidates
+    n, m, d_ell = 1 << 21, 1 << 26, 64
+    for width in (1, 8):
+        pulls = pull_candidates(n, width, d_ell=d_ell, compiled=True)
+        pulls += pull_frontier_candidates(n, 1 << 19, width=width,
+                                          d_ell=d_ell, compiled=True)
+        assert pulls and all(c % 128 == 0 for c in pulls)
+        assert all(ell_vmem_bytes(c, d_ell, width) <= VMEM_CAP
+                   for c in pulls)
+        pushes = push_candidates(n, m, width=width, compiled=True)
+        assert pushes
+        for block_e, bin_n, _ in pushes:
+            assert block_e % 128 == 0 and bin_n % 128 == 0
+            assert push_vmem_bytes(block_e, bin_n, width) <= VMEM_CAP
+    assert any(b == n for _, b, _ in push_candidates(n, m))
 
 
 def test_pallas_pull_charges_ell_cost_and_scans_all(ragged_graph):

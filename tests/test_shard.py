@@ -298,3 +298,15 @@ def test_sharded_solve_parity_across_shard_counts():
                  "batch bfs ok: True", "batch sssp ok: True",
                  "overpartition ok: True"):
         assert line in r.stdout, (line, r.stdout + r.stderr)
+
+
+def test_scaling_benches_fork_fake_devices_on_cpu_hosts_only(monkeypatch):
+    """The scaling suites fake devices in a child process: pinned to the
+    CPU, and refused on a TPU host, where this process holds the chip
+    and a child reaching for it would fail or hang."""
+    from benchmarks import common
+    env = common.fake_device_env()
+    assert env["JAX_PLATFORMS"] == "cpu" and "XLA_FLAGS" not in env
+    monkeypatch.setattr(common.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="TPU host"):
+        common.fake_device_env()
