@@ -38,9 +38,11 @@ solves hit the jit cache like the hand-rolled loops they replaced.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from .core.algorithms.betweenness import (betweenness_finalize,
                                           betweenness_init,
@@ -225,6 +227,9 @@ BACKEND_SHORTHANDS: dict[str, ExchangeBackend] = {
 # solve(trace=True) records up to this many steps
 _DEFAULT_TRACE_CAPACITY = 256
 
+# numbers each solve() call; its profiler spans carry the number
+_SOLVE_SEQ = itertools.count()
+
 # runtime kwargs that name vertices and must index into [0, n); JAX
 # scatter semantics would otherwise clip/drop bad indices silently
 _VERTEX_KEYS = ("root", "source")
@@ -376,70 +381,83 @@ def solve(g: Graph, algorithm: str, *,
             backend) combination the algorithm declares unsupported, or
             a ``root``/``source`` vertex index outside ``[0, n)``.
     """
-    spec = get_spec(algorithm)
-    for vkey in _VERTEX_KEYS:
-        if vkey in kw:
-            validate_vertex_indices(g, vkey, kw[vkey])
-    policy = (spec.default_policy if policy is None
-              else _resolve_policy(policy))
-    backend = _resolve_backend(backend, g)
-    trace_capacity = (_DEFAULT_TRACE_CAPACITY if trace is True
-                      else int(trace))
-    if telemetry is not None and trace_capacity == 0:
-        # telemetry needs the in-loop StepTrace rows to audit against
-        trace_capacity = _DEFAULT_TRACE_CAPACITY
-    static_kw = {k: v for k, v in kw.items() if k not in spec.runtime_keys}
+    # host spans on the profiler's clock (no-ops while no profile is
+    # being taken); the sequence number ties one call's spans together
+    with TraceAnnotation("repro.solve", solve=next(_SOLVE_SEQ)):
+        with TraceAnnotation("repro.solve.prepare"):
+            spec = get_spec(algorithm)
+            for vkey in _VERTEX_KEYS:
+                if vkey in kw:
+                    validate_vertex_indices(g, vkey, kw[vkey])
+            policy = (spec.default_policy if policy is None
+                      else _resolve_policy(policy))
+            backend = _resolve_backend(backend, g)
+            trace_capacity = (_DEFAULT_TRACE_CAPACITY if trace is True
+                              else int(trace))
+            if telemetry is not None and trace_capacity == 0:
+                # telemetry needs the in-loop StepTrace rows to audit
+                # against
+                trace_capacity = _DEFAULT_TRACE_CAPACITY
+            static_kw = {k: v for k, v in kw.items()
+                         if k not in spec.runtime_keys}
 
-    def build_engine() -> PushPullEngine:
-        try:
-            program, default_steps = spec.build(
-                g, policy=policy, backend=backend, **static_kw)
-        except (NotImplementedError, ValueError) as e:
-            raise ValueError(
-                f"algorithm {algorithm!r} does not support the "
-                f"combination policy={policy.name} × "
-                f"backend={backend.name}: {e}") from e
-        return PushPullEngine(
-            program=program, policy=policy,
-            max_steps=default_steps if max_steps is None else max_steps,
-            backend=backend, trace_capacity=trace_capacity)
+            def build_engine() -> PushPullEngine:
+                try:
+                    program, default_steps = spec.build(
+                        g, policy=policy, backend=backend, **static_kw)
+                except (NotImplementedError, ValueError) as e:
+                    raise ValueError(
+                        f"algorithm {algorithm!r} does not support the "
+                        f"combination policy={policy.name} × "
+                        f"backend={backend.name}: {e}") from e
+                return PushPullEngine(
+                    program=program, policy=policy,
+                    max_steps=(default_steps if max_steps is None
+                               else max_steps),
+                    backend=backend, trace_capacity=trace_capacity)
 
-    # key on the spec itself: re-registering a name invalidates cached
-    # engines built from the old spec
-    engine = _ENGINE_CACHE.get_or_build(
-        (algorithm, spec, policy, backend,
-         tuple(sorted(static_kw.items())),
-         g.n, g.m, g.d_ell, max_steps, trace_capacity), build_engine)
-    init_state, init_frontier = spec.init(g, **kw)
-    if checkpoint_every and not engine.supports_stepwise:
-        raise ValueError(
-            f"checkpoint_every is supported for flat programs only; "
-            f"{algorithm!r} is phase-structured (its epoch/phase loop "
-            "runs fully jitted)")
-    guards = bool(check_finite) or checkpoint_every > 0
-    if telemetry is None:
-        if guards and engine.supports_stepwise:
-            res = _run_stepwise_resilient(
-                engine, g, init_state, init_frontier,
-                check_finite=check_finite,
-                checkpoint_every=checkpoint_every)
-        else:
-            res = engine.run(g, init_state, init_frontier)
-            if check_finite:
-                # phase programs run fully jitted: the guard still
-                # refuses to hand back poisoned state, at run end
-                PushPullEngine._check_finite(res.state, check_finite,
-                                             int(res.steps))
-    else:
-        res = _solve_observed(telemetry, engine, g, init_state,
-                              init_frontier, algorithm=algorithm,
-                              policy=policy, backend=backend,
-                              check_finite=check_finite,
-                              checkpoint_every=checkpoint_every)
-    return RunResult(state=spec.finalize(g, res.state), cost=res.cost,
-                     steps=res.steps, push_steps=res.push_steps,
-                     converged=res.converged, epochs=res.epochs,
-                     trace=res.trace)
+            # key on the spec itself: re-registering a name invalidates
+            # cached engines built from the old spec
+            engine = _ENGINE_CACHE.get_or_build(
+                (algorithm, spec, policy, backend,
+                 tuple(sorted(static_kw.items())),
+                 g.n, g.m, g.d_ell, max_steps, trace_capacity),
+                build_engine)
+        with TraceAnnotation("repro.solve.init"):
+            init_state, init_frontier = spec.init(g, **kw)
+        with TraceAnnotation("repro.solve.run"):
+            if checkpoint_every and not engine.supports_stepwise:
+                raise ValueError(
+                    f"checkpoint_every is supported for flat programs "
+                    f"only; {algorithm!r} is phase-structured (its "
+                    "epoch/phase loop runs fully jitted)")
+            guards = bool(check_finite) or checkpoint_every > 0
+            if telemetry is None:
+                if guards and engine.supports_stepwise:
+                    res = _run_stepwise_resilient(
+                        engine, g, init_state, init_frontier,
+                        check_finite=check_finite,
+                        checkpoint_every=checkpoint_every)
+                else:
+                    res = engine.run(g, init_state, init_frontier)
+                    if check_finite:
+                        # phase programs run fully jitted: the guard
+                        # still refuses to hand back poisoned state, at
+                        # run end
+                        PushPullEngine._check_finite(
+                            res.state, check_finite, int(res.steps))
+            else:
+                res = _solve_observed(telemetry, engine, g, init_state,
+                                      init_frontier, algorithm=algorithm,
+                                      policy=policy, backend=backend,
+                                      check_finite=check_finite,
+                                      checkpoint_every=checkpoint_every)
+        with TraceAnnotation("repro.solve.finalize"):
+            return RunResult(state=spec.finalize(g, res.state),
+                             cost=res.cost, steps=res.steps,
+                             push_steps=res.push_steps,
+                             converged=res.converged, epochs=res.epochs,
+                             trace=res.trace)
 
 
 def _run_stepwise_resilient(engine: PushPullEngine, g: Graph,

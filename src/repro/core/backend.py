@@ -110,15 +110,18 @@ class ExchangeBackend:
               msg_fn: Optional[Callable] = None,
               touched: Optional[jax.Array] = None,
               cost: Cost = Cost()) -> tuple[jax.Array, Cost]:
+        def push(v, f, c):
+            with jax.named_scope("exchange.push"):
+                return self.push(g, v, f, combine, msg_fn, c)
+
+        def pull(v, f, c):
+            with jax.named_scope("exchange.pull"):
+                return self.pull(g, v, touched, combine, msg_fn, c)
+
         if isinstance(direction, Direction):
-            if direction == Direction.PUSH:
-                return self.push(g, values, frontier, combine, msg_fn, cost)
-            return self.pull(g, values, touched, combine, msg_fn, cost)
-        return jax.lax.cond(
-            direction,
-            lambda v, f, c: self.push(g, v, f, combine, msg_fn, c),
-            lambda v, f, c: self.pull(g, v, touched, combine, msg_fn, c),
-            values, frontier, cost)
+            return (push if direction == Direction.PUSH else pull)(
+                values, frontier, cost)
+        return jax.lax.cond(direction, push, pull, values, frontier, cost)
 
     # -- graph-sized views the engine passes in ----------------------------
     def operands(self):

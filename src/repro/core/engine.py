@@ -271,7 +271,7 @@ class PushPullEngine:
             return ((~st.converged) & (~st.handoff)
                     & (st.step < phase.max_steps))
 
-        def body(st: _Loop):
+        def step(st: _Loop):
             unvisited = ~st.visited
             # the program's pull destination set and wire values are
             # direction-independent, so they can inform the decision
@@ -286,15 +286,16 @@ class PushPullEngine:
                     touched = unvisited
                 else:
                     touched = None
-            stats = (self._step_stats(g, prog, st, unvisited, touched,
-                                      values)
-                     if (fixed_dir is None or tracing) else None)
-            if fixed_dir is not None:
-                direction = fixed_dir
-                do_push = jnp.bool_(fixed_dir == Direction.PUSH)
-            else:
-                direction = do_push = self.policy.decide(
-                    g, st.frontier, stats)
+            with jax.named_scope("policy.decide"):
+                stats = (self._step_stats(g, prog, st, unvisited, touched,
+                                          values)
+                         if (fixed_dir is None or tracing) else None)
+                if fixed_dir is not None:
+                    direction = fixed_dir
+                    do_push = jnp.bool_(fixed_dir == Direction.PUSH)
+                else:
+                    direction = do_push = self.policy.decide(
+                        g, st.frontier, stats)
             cost = st.cost
             xstate = st.xstate
             if prog.local_fn is not None:
@@ -305,38 +306,46 @@ class PushPullEngine:
                     g, values, st.frontier, direction=direction,
                     combine=prog.combine, msg_fn=prog.msg_fn,
                     touched=touched, cost=cost, xstate=xstate)
-                state, frontier, conv = prog.update_fn(st.state, msgs,
-                                                       st.step)
-                if prog.k_filter_push:
-                    # push produced a sparse updated set -> k-filter
-                    # compacts it (paper: pull inspects every vertex)
-                    kf_set = (frontier if prog.k_filter_set_fn is None
-                              else prog.k_filter_set_fn(st.state, state,
-                                                        frontier))
-                    _, cost = jax.lax.cond(
-                        do_push, k_filter, lambda f, c: (f, c), kf_set,
-                        cost)
-            cost = cost.charge(iterations=1, barriers=1,
-                               **dict(prog.step_charges))
-            if prog.charge_fn is not None:
-                cost = cost.charge(**prog.charge_fn(g, st.state,
-                                                    st.frontier))
-            handoff = st.handoff
-            if greedy:
-                active = jnp.sum(frontier.astype(counter_dtype()))
-                handoff = (~conv) & self.policy.should_handoff(g, active)
-            trace = st.trace
-            if tracing:
-                delta = jax.tree.map(lambda a, b: a - b, cost, st.cost)
-                trace = st.trace.record(
-                    steps0 + st.step, do_push, stats, delta,
-                    predicted_push=predictor.predict_push(stats),
-                    predicted_pull=predictor.predict_pull(stats))
+            with jax.named_scope("program.update"):
+                if prog.local_fn is None:
+                    state, frontier, conv = prog.update_fn(st.state, msgs,
+                                                           st.step)
+                    if prog.k_filter_push:
+                        # push produced a sparse updated set -> k-filter
+                        # compacts it (paper: pull inspects every vertex)
+                        kf_set = (frontier if prog.k_filter_set_fn is None
+                                  else prog.k_filter_set_fn(st.state, state,
+                                                            frontier))
+                        _, cost = jax.lax.cond(
+                            do_push, k_filter, lambda f, c: (f, c), kf_set,
+                            cost)
+                cost = cost.charge(iterations=1, barriers=1,
+                                   **dict(prog.step_charges))
+                if prog.charge_fn is not None:
+                    cost = cost.charge(**prog.charge_fn(g, st.state,
+                                                        st.frontier))
+                handoff = st.handoff
+                if greedy:
+                    active = jnp.sum(frontier.astype(counter_dtype()))
+                    handoff = (~conv) & self.policy.should_handoff(g,
+                                                                   active)
+                trace = st.trace
+                if tracing:
+                    delta = jax.tree.map(lambda a, b: a - b, cost, st.cost)
+                    trace = st.trace.record(
+                        steps0 + st.step, do_push, stats, delta,
+                        predicted_push=predictor.predict_push(stats),
+                        predicted_pull=predictor.predict_pull(stats))
             return _Loop(state=state, frontier=frontier,
                          visited=st.visited | frontier, converged=conv,
                          handoff=handoff, step=st.step + 1, cost=cost,
                          pushes=st.pushes + do_push.astype(jnp.int32),
                          last_push=do_push, trace=trace, xstate=xstate)
+
+        def body(st: _Loop):
+            # profiler scopes: compile-time op metadata, no added ops
+            with jax.named_scope("engine.step"):
+                return step(st)
 
         # an empty entering frontier is already converged (matches the
         # seed loops, whose cond checked the frontier before any work)

@@ -30,6 +30,8 @@ import contextlib
 import time
 from typing import Any, Iterator
 
+from jax.profiler import TraceAnnotation
+
 from .metrics import MetricRegistry
 
 __all__ = ["Telemetry"]
@@ -127,11 +129,17 @@ class Telemetry:
         ``"X"`` (complete-event) exporter needs. Spans around jitted
         work should end after a ``jax.block_until_ready``, else they
         time dispatch, not execution.
+
+        The span also opens a ``jax.profiler.TraceAnnotation`` of the
+        same name, so under ``jax.profiler.trace`` it lands on the
+        device trace's clock beside the program's ``repro.solve``
+        spans.
         """
         t0 = self.now_us()
         sp = dict(fields)
         try:
-            yield sp
+            with TraceAnnotation(name):
+                yield sp
         finally:
             sp.setdefault("dur_us", round(self.now_us() - t0, 3))
             self.emit("span", name, ts_us=t0, **sp)

@@ -243,20 +243,20 @@ class ShardedBackend(ExchangeBackend):
                                    msg_fn=msg_fn, touched=touched,
                                    cost=cost)
             return out, cost, xstate
+
+        def push(v, f, c, e):
+            with jax.named_scope("exchange.push"):
+                return self._push_ex(g, v, f, combine, msg_fn, c, e)
+
+        def pull(v, f, c, e):
+            with jax.named_scope("exchange.pull"):
+                return self.pull(g, v, touched, combine, msg_fn, c) + (e,)
+
         if isinstance(direction, Direction):
-            if direction == Direction.PUSH:
-                return self._push_ex(g, values, frontier, combine,
-                                     msg_fn, cost, xstate)
-            out, cost = self.pull(g, values, touched, combine, msg_fn,
-                                  cost)
-            return out, cost, xstate
-        return jax.lax.cond(
-            direction,
-            lambda v, f, c, e: self._push_ex(g, v, f, combine, msg_fn,
-                                             c, e),
-            lambda v, f, c, e: self.pull(g, v, touched, combine,
-                                         msg_fn, c) + (e,),
-            values, frontier, cost, xstate)
+            return (push if direction == Direction.PUSH else pull)(
+                values, frontier, cost, xstate)
+        return jax.lax.cond(direction, push, pull, values, frontier, cost,
+                            xstate)
 
     def predict_comm_bytes(self, g, values, frontier):
         return (self._wire_push_bytes(values, frontier),
