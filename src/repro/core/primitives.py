@@ -17,6 +17,13 @@ the Cost charges exactly what the paper's Table 1 counts:
   pull: reads = Σ in_deg(touched dst) (all m when dst set is dense);
         writes = |touched dst|, zero atomics/locks.
 
+Push without ``msg_fn`` masks the wire table once per step, O(n): an
+edge's message ``where(frontier[src], values[src], ident)`` is the same
+selection as ``where(frontier, values, ident)[src]``, bit for bit, so one
+gather of the masked table replaces the per-edge frontier gather. With a
+``msg_fn`` the two differ (``msg_fn(ident, w)`` need not be the identity:
+SSSP's ``x + w`` overflows int32), so that path masks per edge.
+
 TPU note: on static-shape hardware the dense-masked formulation touches
 all m lanes regardless; the Cost model charges the *algorithmic* counts
 (what a frontier-compacted CPU/DM implementation moves), which is what the
@@ -31,6 +38,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..graphs.structure import Graph
 from ..sparse.segment import segment_max, segment_min, segment_sum
@@ -49,16 +57,22 @@ COMBINE_FNS = {
 }
 
 
+def _identity_scalar(combine: str, dtype):
+    """:func:`combine_identity` as a static numpy scalar (for ``fill_value``)."""
+    if combine == "sum":
+        val = 0
+    elif jnp.issubdtype(dtype, jnp.floating):
+        val = np.inf if combine == "min" else -np.inf
+    else:
+        info = jnp.iinfo(dtype)
+        val = info.max if combine == "min" else info.min
+    return np.dtype(dtype).type(val)
+
+
 def combine_identity(combine: str, dtype) -> jax.Array:
     """Reduce identity: what an edge contributes when masked out, and what
     an empty segment holds after the reduce (callers test against it)."""
-    if combine == "sum":
-        return jnp.zeros((), dtype)
-    if jnp.issubdtype(dtype, jnp.floating):
-        val = jnp.inf if combine == "min" else -jnp.inf
-        return jnp.asarray(val, dtype)
-    info = jnp.iinfo(dtype)
-    return jnp.asarray(info.max if combine == "min" else info.min, dtype)
+    return jnp.asarray(_identity_scalar(combine, dtype), dtype)
 
 
 def mask_untouched(out: jax.Array, touched: jax.Array,
@@ -97,16 +111,27 @@ def push_relax(g: Graph, values: jax.Array, frontier: jax.Array,
     values: float/int [n] or [n, d] source payloads.
     frontier: bool[n]; only edges whose src is active contribute.
     Returns combined updates per destination, [n] or [n, d].
+
+    Without ``msg_fn`` the wire table is masked once (scope
+    ``push.premask``) and gathered once per edge: inactive sources send
+    the combine identity, which the combine absorbs. That is exact only
+    without ``msg_fn``, as ``msg_fn(ident, w)`` need not be the identity;
+    with one, the frontier is gathered per edge and masks the messages.
     """
-    active_e = jnp.take(frontier, g.push_src, axis=0, mode="fill",
-                        fill_value=False)
-    msgs = _edge_messages(values, g.push_src, g.push_w, msg_fn)
-    ident = combine_identity(combine, msgs.dtype)
-    if msgs.ndim > 1:
-        active_b = active_e.reshape((-1,) + (1,) * (msgs.ndim - 1))
+    if msg_fn is None:
+        ident = _identity_scalar(combine, values.dtype)
+        fb = frontier.reshape((-1,) + (1,) * (values.ndim - 1))
+        with jax.named_scope("push.premask"):
+            masked = jnp.where(fb, values, ident)
+        msgs = jnp.take(masked, g.push_src, axis=0, mode="fill",
+                        fill_value=ident)
     else:
-        active_b = active_e
-    msgs = jnp.where(active_b, msgs, ident)
+        active_e = jnp.take(frontier, g.push_src, axis=0, mode="fill",
+                            fill_value=False)
+        msgs = _edge_messages(values, g.push_src, g.push_w, msg_fn)
+        active_b = active_e.reshape((-1,) + (1,) * (msgs.ndim - 1))
+        msgs = jnp.where(active_b, msgs,
+                         combine_identity(combine, msgs.dtype))
     out = COMBINE_FNS[combine](msgs, g.push_dst, g.n)
     k = frontier_out_edges(g, frontier)
     width = 1 if values.ndim == 1 else values.shape[-1]

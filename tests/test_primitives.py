@@ -7,12 +7,14 @@ structure (push: combining writes; pull: reads)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, strategies as st
 
 from repro.core import (Cost, spmv_pull, spmspv_push, PLUS_TIMES, MIN_PLUS,
                         OR_AND, push_relax, pull_relax, pull_relax_ell,
                         combine_identity)
-from repro.graphs import erdos_renyi
+from repro.core.primitives import COMBINE_FNS
+from repro.graphs import build_graph, erdos_renyi
 
 
 def _rand_graph(seed, n=64, deg=3.0):
@@ -105,3 +107,90 @@ def test_cost_pytree_arithmetic():
     assert int(c3.locks) == 7 and int(c3.atomics) == 0
     c4 = c.charge_combining_writes(7, float_data=False)
     assert int(c4.atomics) == 7 and int(c4.locks) == 0
+
+
+# -- push's masked wire table ------------------------------------------
+# Vertices 9-11 have no edges; 0 has out-edges to 1, 2, 3, 5 and 8.
+_SRC = [0, 0, 0, 0, 0, 1, 1, 2, 3, 4, 4, 5, 6, 7, 8, 8, 2, 3]
+_DST = [1, 2, 3, 5, 8, 0, 4, 3, 2, 6, 7, 4, 7, 8, 1, 6, 6, 6]
+_N = 12
+
+
+def _old_push_relax(g, values, frontier, combine, msg_fn):
+    """The per-edge formulation: gather the frontier bit of every edge's
+    source and mask the edge's message with it."""
+    active_e = jnp.take(frontier, g.push_src, axis=0, mode="fill",
+                        fill_value=False)
+    msgs = jnp.take(values, g.push_src, axis=0, mode="fill", fill_value=0)
+    if msg_fn is not None:
+        msgs = msg_fn(msgs, g.push_w)
+    active_b = active_e.reshape((-1,) + (1,) * (msgs.ndim - 1))
+    msgs = jnp.where(active_b, msgs, combine_identity(combine, msgs.dtype))
+    return COMBINE_FNS[combine](msgs, g.push_dst, g.n)
+
+
+def _add_weight(x, w):
+    return x + w.reshape(w.shape + (1,) * (x.ndim - 1)).astype(x.dtype)
+
+
+def _values(dtype, width):
+    """Payloads with NaN, -0.0 and +-inf (float) or the int32 extremes."""
+    if dtype == np.float32:
+        col = np.array([np.nan, -0.0, 0.0, np.inf, -np.inf, 1.5, -2.25,
+                        3.0, -0.0, 7.0, np.nan, 2.0], np.float32)
+    else:
+        col = np.array([2**31 - 1, -2**31, 0, 5, -7, 11, 3, -1, 2**31 - 1,
+                        9, 4, -2**31], np.int32)
+    if width == 1:
+        return col
+    return np.stack([col, np.roll(col, 1), np.roll(col, 5)], axis=1)
+
+
+_FRONTIERS = {
+    "empty": np.zeros(_N, bool),
+    "single": np.eye(_N, dtype=bool)[0],
+    "full": np.ones(_N, bool),
+}
+
+
+@pytest.mark.parametrize("msg_fn", [None, _add_weight],
+                         ids=["no_msg_fn", "msg_fn"])
+@pytest.mark.parametrize("frontier", sorted(_FRONTIERS))
+@pytest.mark.parametrize("width", [1, 3])
+@pytest.mark.parametrize("dtype", [np.int32, np.float32])
+@pytest.mark.parametrize("combine", ["sum", "min", "max"])
+def test_push_masked_table_is_bit_exact(combine, dtype, width, frontier,
+                                        msg_fn):
+    """Masking the wire table once per step gives, bit for bit, what
+    masking every edge's message gives: NaN, -0.0 and the extremes
+    included, on a graph with isolated vertices."""
+    g = build_graph(np.array(_SRC), np.array(_DST), _N,
+                    weights=np.linspace(0.5, 9.0, len(_SRC)))
+    x = jnp.asarray(_values(dtype, width))
+    f = jnp.asarray(_FRONTIERS[frontier])
+    got, cost = push_relax(g, x, f, combine=combine, msg_fn=msg_fn)
+    want = _old_push_relax(g, x, f, combine, msg_fn)
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert np.array_equal(got.view(np.uint32), want.view(np.uint32))
+    k = int(np.asarray(g.out_deg)[_FRONTIERS[frontier]].sum())
+    assert int(cost.reads) == k * width
+    if dtype == np.float32 and frontier == "full" and width == 1:
+        assert np.isnan(got).any()   # NaN sources reach the combine
+
+
+def _gathers(msg_fn) -> int:
+    g = build_graph(np.array(_SRC), np.array(_DST), _N)
+    f = jax.jit(lambda g_, x, fr: push_relax(g_, x, fr, combine="min",
+                                             msg_fn=msg_fn))
+    text = f.lower(g, jnp.zeros(_N, jnp.int32),
+                   jnp.zeros(_N, bool)).as_text()
+    return text.count('"stablehlo.gather"(')
+
+
+@pytest.mark.parametrize("msg_fn,want", [(None, 1), (_add_weight, 2)],
+                         ids=["no_msg_fn", "msg_fn"])
+def test_push_gathers_once_per_edge_without_msg_fn(msg_fn, want):
+    """Without ``msg_fn`` the push gathers only the masked wire table; the
+    per-edge frontier gather stays on the ``msg_fn`` path alone."""
+    assert _gathers(msg_fn) == want

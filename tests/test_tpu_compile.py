@@ -8,7 +8,8 @@ see. These tests compile, with ``interpret=False``:
 
   * the three graph kernels at the chip smoke's shapes (n = 2**21,
     d_ell = 64, 32-bit payloads, the GAP urand graph's edge count);
-  * the dense engine's jitted loop for ``bfs``/auto and ``pagerank``;
+  * the dense engine's jitted loop for ``bfs``/auto and ``pagerank``,
+    and the shape of its BFS push (no per-edge frontier gather);
   * the sharded ``bfs``/auto engine on a 4-chip mesh.
 
 Nothing runs, so they say nothing about results or times. The topology
@@ -188,6 +189,22 @@ def test_engine_compiles(one_chip, algorithm, policy, kw, backend):
     # the graph's layouts plus the loop carry fit one chip's 16 GB
     mem = c.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_dense_bfs_push_gathers_a_materialized_masked_table(one_chip):
+    """The compiled BFS push gathers no ``pred[m]`` frontier per edge: the
+    masked wire table is its own fusion (scope ``push.premask``), which
+    the value gather reads, not a select fused back into the gather."""
+    n, m = 2 ** 16, 1_818_572        # the Graph500 Kronecker 2^16 cell
+    g = _graph(n, m, 8, one_chip)
+    eng = _engine(g, "bfs", "auto", DenseBackend())
+    text = jax.jit(eng.run).lower(
+        g, *_init(g, "bfs", one_chip, root=0)).compile().as_text()
+    push = [ln for ln in text.splitlines() if "exchange.push" in ln]
+    assert not [ln for ln in push if f"= pred[{m}]" in ln
+                and (" gather(" in ln or "/gather" in ln)]
+    assert [ln for ln in push if f"= s32[{n}]" in ln and " fusion(" in ln
+            and "push.premask" in ln]
 
 
 def test_sharded_bfs_auto_compiles_on_four_chips(mesh4):
