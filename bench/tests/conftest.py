@@ -5,9 +5,9 @@
 ``tiny_root`` is a copy of the benchmark (``BENCHMARK.json`` and
 ``bench/``) to which configurations and cells of a few hundred
 vertices were added the way a later change would add them: new files
-and new entries, no edit to a file that was there. Its PageRank cell
-brings the PageRank metrics, whose readers, traffic mix and reference
-are in ``bench/`` and which no chip cell reports yet.
+and new entries, no edit to a file that was there. Each tiny cell is
+added to the ``workloads`` of every metric entry whose cells run its
+algorithm, so it reports what the chip cells of that algorithm report.
 """
 
 from __future__ import annotations
@@ -33,23 +33,18 @@ TINY_CELLS = {
     "tiny-urand-pr": ("tiny-urand", "pagerank-gap"),
 }
 
-# PageRank's metrics, as the cell that measures it on the chip would add them
-PR_END_TO_END = [{"name": "pr_iter_ms", "unit": "ms", "better": "lower",
-                  "bound": 0.01, "source": "host_clock",
-                  "workloads": ["tiny-urand-pr"]}]
-PR_PER_LAYER = [
-    {"name": name, "unit": "%", "better": better, "source": "device_trace",
-     "layer": layer, "moves": "pr_iter_ms", "workloads": ["tiny-urand-pr"]}
-    for name, better, layer in (
-        ("pr_roofline", "higher", "exchange backend"),
-        ("device_idle.pr", "lower", "device"))]
-
 
 def copy_benchmark(dest: Path) -> Path:
     shutil.copy(REPO / "BENCHMARK.json", dest / "BENCHMARK.json")
     shutil.copytree(REPO / "bench", dest / "bench", ignore=shutil.ignore_patterns(
         "__pycache__", ".trace", "tests"))
     return dest
+
+
+def algorithm(root: Path, traffic: str) -> str:
+    """The algorithm that the traffic mix ``traffic`` runs."""
+    return json.loads((root / "bench" / "traffic" / f"{traffic}.json")
+                      .read_text())["algorithm"]
 
 
 def add_entries(root: Path, configs=(), workloads=(), per_layer=(),
@@ -76,11 +71,14 @@ def tiny_root(tmp_path) -> Path:
         cells.append({"name": name, "config": config, "traffic": traffic,
                       "chips": 1, "why": "test"})
     bench = json.loads((root / "BENCHMARK.json").read_text())
+    runs = {w["name"]: algorithm(root, w["traffic"])
+            for w in bench["workloads"]}
     for entry in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in entry:
-            entry["workloads"] += [c for c in TINY_CELLS if c.endswith("bfs")]
+            algs = {runs[c] for c in entry["workloads"]}
+            entry["workloads"] += [c for c, (_, traffic) in TINY_CELLS.items()
+                                   if algorithm(root, traffic) in algs]
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
-    add_entries(root, end_to_end=PR_END_TO_END, per_layer=PR_PER_LAYER)
     add_entries(root, configs=configs, workloads=cells)
     return root
 

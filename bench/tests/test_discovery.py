@@ -8,7 +8,7 @@ import json
 
 import pytest
 
-from conftest import add_entries, copy_benchmark, run_tiny
+from conftest import REPO, add_entries, copy_benchmark, run_tiny
 from bench.spec import load_cell
 
 
@@ -124,3 +124,44 @@ def test_unknown_cell_is_refused(tmp_path):
     root = copy_benchmark(tmp_path)
     with pytest.raises(KeyError, match="no workload"):
         load_cell(root, "no-such-cell")
+
+
+BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
+CELLS = [w["name"] for w in BENCH["workloads"]]
+METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_every_cell_of_the_benchmark_resolves(name):
+    """Each cell finds its configuration, traffic, generator, driver,
+    reference and readers by the names it gives, and reports set-up, an
+    end-to-end metric besides it, and a per-layer metric."""
+    cell = load_cell(REPO, name)
+    assert cell.config["generator"] and cell.traffic["algorithm"]
+    cell.module("generators", cell.config["generator"]).generate
+    cell.module("traffic", cell.traffic["driver"]).make
+    ref = cell.module("reference", cell.traffic["algorithm"])
+    assert callable(ref.compare) and callable(ref.control)
+    for entry in cell.end_to_end + cell.per_layer:
+        assert callable(cell.module("metrics", entry["name"]).read)
+    e2e = {e["name"] for e in cell.end_to_end}
+    assert "setup_s" in e2e and len(e2e) >= 2 and cell.per_layer
+    assert e2e >= {e["moves"] for e in cell.per_layer}
+
+
+def test_every_metric_has_its_reader_and_names_only_cells_that_exist():
+    for entry in METRICS:
+        assert (REPO / "bench" / "metrics" / f"{entry['name']}.py").is_file()
+        assert set(entry.get("workloads", ())) <= set(CELLS), entry["name"]
+    names = [e["name"] for e in METRICS]
+    assert len(names) == len(set(names))
+
+
+def test_pagerank_metrics_go_to_the_pagerank_cell_alone():
+    def e2e(name):
+        return {e["name"] for e in load_cell(REPO, name).end_to_end}
+    assert e2e("urand21-pr") >= {"pr_iter_ms", "peak_hbm_gb", "setup_s"}
+    assert {e["name"] for e in load_cell(REPO, "urand21-pr").per_layer} >= {
+        "pr_roofline", "device_idle.pr", "pull_iter_ms.pr"}
+    for name in ("urand21-bfs", "kron16-bfs"):
+        assert "pr_iter_ms" not in e2e(name)

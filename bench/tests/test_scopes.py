@@ -170,3 +170,46 @@ def test_new_metrics_read_nothing_untraced(tmp_path):
     run.trace = None
     assert all(_read(name, run) is None for name in NEW_METRICS)
 
+
+
+URAND21_PR = DATA / "urand21-pr.xplane.pb"
+
+
+def _pr_run(tmp_path, trace: Path):
+    """A traced PageRank run of one 20-iteration solve, as the readers
+    see it."""
+    run = _traced_run(tmp_path, trace, steps=20, push_steps=0)
+    run.algorithm = "pagerank"
+    run.cell.traffic = {"params": {"iters": 20}}
+    return run
+
+
+def test_pull_iter_ms_on_the_urand21_pr_chip_trace(tmp_path):
+    """A traced urand21-pr run on a v5e chip: one solve of 20 pull
+    iterations over 67M edges. The run printed busy_s=21.814473 and
+    pull_iter_ms.pr 1090.6858448 from this file."""
+    ops, modules, spans = scopes.read_xspace(URAND21_PR, {0})
+    sc = scopes.reduce(ops, modules, spans)
+    assert sc.busy_s == pytest.approx(21.814473, abs=1e-6)
+    # the pull is all but ~0.003% of the device's work
+    assert sc.own_s["exchange.pull"] >= 0.9999 * sc.busy_s
+    assert "exchange.push" not in sc.own_s
+    got = _read("pull_iter_ms.pr", _pr_run(tmp_path, URAND21_PR))
+    assert got == pytest.approx(1090.6858448, rel=1e-9)
+    assert got == pytest.approx(1e3 * sc.own_s["exchange.pull"] / 20)
+
+
+def test_pull_iter_ms_on_a_program_without_scopes(tmp_path):
+    assert _read("pull_iter_ms.pr", _pr_run(tmp_path, URAND21)) is None
+
+
+def test_pull_iter_ms_reads_nothing_for_bfs(tmp_path):
+    run = _traced_run(tmp_path, KRON16, steps=6, push_steps=4)
+    run.cell.traffic = {"params": {}}
+    assert _read("pull_iter_ms.pr", run) is None
+
+
+def test_pull_iter_ms_reads_nothing_untraced(tmp_path):
+    run = _pr_run(tmp_path, URAND21_PR)
+    run.trace = None
+    assert _read("pull_iter_ms.pr", run) is None
