@@ -144,7 +144,8 @@ def build_graph(src, dst, n: int, weights=None, d_ell: Optional[int] = None,
                 f"build_graph: {name}[{bad}] = {int(arr[bad])} is "
                 f"outside the vertex range [0, {n}) — every edge "
                 f"endpoint must name an existing vertex")
-    if weights is None:
+    unweighted = weights is None
+    if unweighted:
         weights = np.ones(m, dtype=np.float32)
     w = np.asarray(weights, dtype=np.float32)
     if w.shape != (m,):
@@ -178,10 +179,13 @@ def build_graph(src, dst, n: int, weights=None, d_ell: Optional[int] = None,
     ell_idx, ell_w = _ell_from_ptr(in_ptr, p_src, p_w, n, d_ell)
 
     dev = jnp.asarray
+    coo_w = dev(p_w)
+    # unit weights read the same in either edge order: one device array
+    push_w = coo_w if unweighted else dev(q_w)
     return Graph(
-        coo_src=dev(p_src), coo_dst=dev(p_dst), coo_w=dev(p_w),
+        coo_src=dev(p_src), coo_dst=dev(p_dst), coo_w=coo_w,
         in_ptr=dev(in_ptr),
-        push_src=dev(q_src), push_dst=dev(q_dst), push_w=dev(q_w),
+        push_src=dev(q_src), push_dst=dev(q_dst), push_w=push_w,
         out_ptr=dev(out_ptr),
         ell_idx=dev(ell_idx), ell_w=dev(ell_w),
         in_deg=dev(in_deg), out_deg=dev(out_deg),

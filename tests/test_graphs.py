@@ -58,6 +58,20 @@ def test_build_graph_boundary_vertex_still_accepted():
     assert build_graph(np.array([]), np.array([]), 3).m == 0
 
 
+@pytest.mark.parametrize("weighted", [False, True])
+def test_unit_weights_are_one_array(weighted):
+    """An unweighted graph keeps its unit weights once for both edge
+    orders; a weighted one keeps each order's own."""
+    rng = np.random.default_rng(3)
+    src, dst = rng.integers(0, 50, (2, 300))
+    w = rng.random(300) + 0.5 if weighted else None
+    g = build_graph(src, dst, 50, weights=w)
+    assert (g.push_w is g.coo_w) != weighted
+    want = np.ones(300) if w is None else w[np.argsort(src, kind="stable")]
+    np.testing.assert_array_equal(np.asarray(g.push_w),
+                                  want.astype(np.float32))
+
+
 def test_ell_covers_all_in_edges(small_graph):
     g = small_graph
     idx = np.asarray(g.ell_idx)

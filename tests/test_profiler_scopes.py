@@ -93,6 +93,26 @@ def test_backend_directions_carry_their_scope(g, make, direction):
     assert seen == want
 
 
+@pytest.mark.parametrize("combine,want", [
+    ("min", {"push.frontier", "push.premask"}), ("sum", {"push.premask"})])
+def test_push_paths_carry_their_scope(combine, want):
+    """On a graph with push capacities a min push compiles both of its
+    paths, each under its own scope inside ``exchange.push``; a sum push
+    has the all-edge path alone."""
+    big = kronecker(10, edge_factor=16, seed=4)
+
+    def push(v, f):
+        return DenseBackend().relax(big, v, f, direction=Direction.PUSH,
+                                    combine=combine)[0]
+    names = _op_names(jax.jit(push).lower(
+        jnp.zeros(big.n, jnp.int32), jnp.arange(big.n) % 5 == 0
+    ).compile().as_text())
+    seen = {s for s in ("push.frontier", "push.premask")
+            if any(f"/exchange.push/" in n and f"/{s}/" in n
+                   for n in names)}
+    assert seen == want
+
+
 def _host_spans(log_dir) -> list:
     """``[(start_ns, end_ns, name, stats)]`` of the host planes."""
     from jax.profiler import ProfileData

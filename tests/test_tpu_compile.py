@@ -10,6 +10,8 @@ see. These tests compile, with ``interpret=False``:
     d_ell = 64, 32-bit payloads, the GAP urand graph's edge count);
   * the dense engine's jitted loop for ``bfs``/auto and ``pagerank``,
     and the shape of its BFS push (no per-edge frontier gather);
+  * the frontier push in each of its capacities at the urand graph's
+    size, within the dense push's temporary memory;
   * the sharded ``bfs``/auto engine on a 4-chip mesh.
 
 Nothing runs, so they say nothing about results or times. The topology
@@ -32,6 +34,8 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
 from repro import api
 from repro.core.backend import DenseBackend, PallasBackend
 from repro.core.engine import PushPullEngine
+from repro.core.primitives import (_frontier_push, _masked_push, push_caps,
+                                   push_relax)
 from repro.graphs import Graph, erdos_renyi
 from repro.graphs.partition import partition_1d
 from repro.kernels.coo_push import (PushBinPlan, coo_push_pallas,
@@ -205,6 +209,35 @@ def test_dense_bfs_push_gathers_a_materialized_masked_table(one_chip):
                 and (" gather(" in ln or "/gather" in ln)]
     assert [ln for ln in push if f"= s32[{n}]" in ln and " fusion(" in ln
             and "push.premask" in ln]
+
+
+def _temp_bytes(fn, *args):
+    mem = jax.jit(fn).lower(*args).compile().memory_analysis()
+    return None if mem is None else mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("width", [1, 8])
+def test_frontier_push_compiles_in_every_capacity(one_chip, width):
+    """Each capacity of the frontier push compiles with static shapes at
+    the urand graph's size, within the temporary memory of the all-edge
+    masked push it stands in for, and so does the switched push."""
+    g = _graph(N, M, D_ELL, one_chip)
+    x = _sds((N,) if width == 1 else (N, width), I32, one_chip)
+    f = _sds((N,), jnp.bool_, one_chip)
+    rank = ids = _sds((N,), I32, one_chip)
+    dense = _temp_bytes(lambda g_, x_, f_: _masked_push(g_, x_, f_, "min"),
+                        g, x, f)
+    caps = push_caps(M)
+    assert caps[0] >= 2 ** 10 and caps[-1] == M // 8
+    for cap in caps:
+        temp = _temp_bytes(lambda g_, x_, r_, i_, c=cap: _frontier_push(
+            g_, x_, r_, i_, "min", c), g, x, rank, ids)
+        assert dense is None or temp <= dense, (cap, temp, dense)
+    # switched, the capacities' tables share the all-edge push's space;
+    # what the compiler adds around the branches stays within 2%
+    temp = _temp_bytes(lambda g_, x_, f_: push_relax(g_, x_, f_, "min")[0],
+                       g, x, f)
+    assert dense is None or temp <= 1.02 * dense, (temp, dense)
 
 
 def test_sharded_bfs_auto_compiles_on_four_chips(mesh4):
